@@ -110,4 +110,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     sys.exit(main())

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Sequence
 
 import numpy as np
@@ -37,13 +38,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from .summarization import SummarizationConfig, breakpoints
-from ..compat import axis_size as _compat_axis_size, make_mesh, shard_map
 from ..kernels import ref
 
 _SENTINEL = jnp.uint32(0xFFFFFFFF)
+_log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,10 +57,10 @@ class DistBuildConfig:
 
 def _axis_size(axis_names) -> int:
     if isinstance(axis_names, str):
-        return _compat_axis_size(axis_names)
+        return lax.axis_size(axis_names)
     size = 1
     for a in axis_names:
-        size *= _compat_axis_size(a)
+        size *= lax.axis_size(a)
     return size
 
 
@@ -213,7 +214,7 @@ def make_build_fn(mesh, axes: Sequence[str], cfg: DistBuildConfig):
 
     @jax.jit
     def build(series, ids):
-        f = shard_map(
+        f = jax.shard_map(
             functools.partial(build_local, cfg=cfg, axis_names=tuple(axes)),
             mesh=mesh,
             in_specs=(spec_in, spec_in),
@@ -236,7 +237,8 @@ def default_batch_mesh():
     qs = 1
     while (qs * 2) * (qs * 2) <= n and n % (qs * 2) == 0:
         qs *= 2
-    return make_mesh((qs, n // qs), ("q", "r"))
+    return jax.make_mesh((qs, n // qs), ("q", "r"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 _mesh_topk_cache: dict = {}
@@ -253,12 +255,13 @@ def _mesh_topk_fn(mesh, ksel: int):
     fn = _mesh_topk_cache.get(key)
     if fn is not None:
         return fn
-    axes = mesh.axis_names
-    axis_q, axes_r = axes[0], tuple(axes[1:])
+    axes_r = tuple(mesh.axis_names[1:])
 
     def body(q, x):
         xl = x.reshape(-1, x.shape[-1])  # (E_local, n)
-        g = q @ xl.T  # f32 matmul-form screen — the MXU pass
+        # f32 matmul-form screen — the MXU pass, at full f32 precision: the
+        # executor certifies the slate against the f32 matmul error bound
+        g = jnp.matmul(q, xl.T, precision=lax.Precision.HIGHEST)
         qsq = jnp.sum(q * q, axis=1)
         xsq = jnp.sum(xl * xl, axis=1)
         d2 = qsq[:, None] + xsq[None, :] - 2.0 * g
@@ -268,7 +271,7 @@ def _mesh_topk_fn(mesh, ksel: int):
             return -nv, ni.astype(jnp.int32)
         ridx = jnp.int32(0)
         for a in axes_r:  # flatten the runs axes into one shard index
-            ridx = ridx * _compat_axis_size(a) + lax.axis_index(a)
+            ridx = ridx * lax.axis_size(a) + lax.axis_index(a)
         gi = ni.astype(jnp.int32) + ridx * xl.shape[0]
         av = lax.all_gather(nv, axes_r, tiled=False)  # (nr, mq, kk)
         ai = lax.all_gather(gi, axes_r, tiled=False)
@@ -279,19 +282,36 @@ def _mesh_topk_fn(mesh, ksel: int):
         fv, fi = lax.top_k(av, min(ksel, nr * kk))  # fold the shard slates
         return -fv, jnp.take_along_axis(ai, fi, axis=1)
 
-    x_spec = P(axes_r) if axes_r else P()
-    f = shard_map(
+    q_in, x_in = shardings = _mesh_shardings(mesh)
+    f = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(P(axis_q), x_spec),
-        out_specs=(P(axis_q), P(axis_q)),
+        in_specs=(q_in.spec, x_in.spec),
+        out_specs=(q_in.spec, q_in.spec),
         # runs-axis outputs are all_gather-reduced (identical on every r
         # shard); the static replication checker cannot infer that.
         check_vma=False,
     )
-    fn = jax.jit(f)
+    fn = jax.jit(f, in_shardings=shardings)
     _mesh_topk_cache[key] = fn
     return fn
+
+
+def _mesh_shardings(mesh) -> tuple[NamedSharding, NamedSharding]:
+    """Where the mesh top-k's inputs live: the padded query batch (mp, n)
+    split by rows over the first mesh axis, the stacked candidate groups
+    (rs, e, n) over the remaining axes, so each device holds only its own
+    tile."""
+    axes = mesh.axis_names
+    x_spec = P(tuple(axes[1:])) if len(axes) > 1 else P()
+    return NamedSharding(mesh, P(axes[0])), NamedSharding(mesh, x_spec)
+
+
+def _placement(arr) -> str:
+    """Which device holds which rows of a sharded array."""
+    return f"{arr.shape}: " + ", ".join(
+        f"device {s.device.id} holds [{s.index[0].start}:{s.index[0].stop}]"
+        for s in arr.addressable_shards)
 
 
 def mesh_topk_candidates(Q, X, ksel: int, *, mesh=None):
@@ -308,7 +328,7 @@ def mesh_topk_candidates(Q, X, ksel: int, *, mesh=None):
 
     Candidate rows are padded to a power-of-two-per-shard grid with +large
     sentinels so jit sees a handful of stable shapes across serving
-    batches."""
+    batches. Each call logs where its inputs landed at DEBUG level."""
     mesh = mesh if mesh is not None else default_batch_mesh()
     axes = mesh.axis_names
     qs = mesh.shape[axes[0]]
@@ -329,7 +349,11 @@ def mesh_topk_candidates(Q, X, ksel: int, *, mesh=None):
     mp = -(-m // qs) * qs
     qp = np.zeros((mp, n), np.float32)
     qp[:m] = Q
-    d2, rows = _mesh_topk_fn(mesh, ksel)(jnp.asarray(qp), xp.reshape(rs, e, n))
+    args = jax.device_put((qp, xp.reshape(rs, e, n)), _mesh_shardings(mesh))
+    if _log.isEnabledFor(logging.DEBUG):
+        _log.debug("mesh top-k queries %s; candidates %s",
+                   *(_placement(a) for a in args))
+    d2, rows = _mesh_topk_fn(mesh, ksel)(*args)
     d2 = np.asarray(d2)[:m]
     rows = np.asarray(rows).astype(np.int64)[:m]
     return d2, np.where(rows >= c, -1, rows)
@@ -358,7 +382,7 @@ def make_query_fn(mesh, axes: Sequence[str], cfg: DistBuildConfig, *, k=10, veri
 
     @jax.jit
     def query(index, queries):
-        f = shard_map(
+        f = jax.shard_map(
             functools.partial(
                 query_local, cfg=cfg, axis_names=tuple(axes), k=k,
                 verify_budget=verify_budget,

@@ -58,6 +58,11 @@ class BufferChunk:
         return int(self.series.shape[0])
 
 
+def _slice_chunk(c: BufferChunk, lo: int, hi: Optional[int]) -> BufferChunk:
+    return BufferChunk(c.series[lo:hi], c.ids[lo:hi],
+                       None if c.ts is None else c.ts[lo:hi])
+
+
 @dataclasses.dataclass(frozen=True)
 class RunSet:
     """An immutable snapshot of the whole ingest state at one epoch."""
@@ -208,17 +213,23 @@ class RunRegistry:
             n = min(n, avail)
             if n <= 0:
                 return None, cur
-            series = np.concatenate([c.series for c in cur.buffer])
-            ids = np.concatenate([c.ids for c in cur.buffer])
+            # copy only the chunks the flush takes: concatenating the whole
+            # buffer would make a large async backlog cost quadratic time
+            parts, need, i = [], n, 0
+            while need:
+                c = cur.buffer[i]
+                parts.append(c if c.n <= need else _slice_chunk(c, 0, need))
+                need -= parts[-1].n
+                i += 1
+            rest = cur.buffer[i:]
+            if parts[-1] is not cur.buffer[i - 1]:  # split the last chunk
+                rest = (_slice_chunk(cur.buffer[i - 1], parts[-1].n, None),
+                        ) + rest
             ts = None
-            if all(c.ts is not None for c in cur.buffer):
-                ts = np.concatenate([c.ts for c in cur.buffer])
-            taken = BufferChunk(series[:n], ids[:n],
-                                None if ts is None else ts[:n])
-            rest: Tuple[BufferChunk, ...] = ()
-            if n < avail:
-                rest = (BufferChunk(series[n:], ids[n:],
-                                    None if ts is None else ts[n:]),)
+            if all(c.ts is not None for c in parts):
+                ts = np.concatenate([c.ts for c in parts])
+            taken = BufferChunk(np.concatenate([c.series for c in parts]),
+                                np.concatenate([c.ids for c in parts]), ts)
             snap = self._install_locked(cur._with(buffer=rest,
                                            flushing=cur.flushing + (taken,)))
             return taken, snap

@@ -127,18 +127,12 @@ def _screen_select_body(
 
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
-    qn2 = jnp.sum(q * q, axis=-1)  # (bm,) — the certificate's |q|^2 term
+    qn2 = jnp.sum(q * q, axis=-1, keepdims=True)  # (bm, 1) certificate |q|^2
     qn2_ref[...] = qn2  # idempotent across the candidate axis
     # matmul-form screen with the PRECOMPUTED candidate norms: the arena
     # caches |x|^2 once per table, so the tile pays one MXU contraction and
     # two rank-1 corrections — never a second pass over x
-    d2 = (
-        qn2[:, None]
-        + xn2_ref[...][None, :]
-        - 2.0 * jax.lax.dot_general(
-            q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    )  # (bm, bn)
+    d2 = qn2 + xn2_ref[...] - 2.0 * _cross(q, x)  # (bm, bn)
     tile_idx = (
         jax.lax.broadcasted_iota(jnp.int32, (d2.shape[0], block_n), 1)
         + j * block_n
@@ -165,17 +159,32 @@ def _screen_select_quant_body(
 
     q = q_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)  # in-register int8 -> f32 upcast
-    qn2 = jnp.sum(q * q, axis=-1)  # (bm,) — the certificate's |q|^2 term
+    qn2 = jnp.sum(q * q, axis=-1, keepdims=True)  # (bm, 1) certificate |q|^2
     qn2_ref[...] = qn2  # idempotent across the candidate axis
-    g = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * s_ref[...][None, :]  # (bm, bn) dequantized cross term
-    d2 = qn2[:, None] + xn2_ref[...][None, :] - 2.0 * g
+    g = _cross(q, x) * s_ref[...]  # (bm, bn) dequantized cross term
+    d2 = qn2 + xn2_ref[...] - 2.0 * g
     tile_idx = (
         jax.lax.broadcasted_iota(jnp.int32, (d2.shape[0], block_n), 1)
         + j * block_n
     )
     _merge_topk_tile(vals_ref, idxs_ref, d2, tile_idx, k)
+
+
+def _cross(q, x) -> jnp.ndarray:
+    """(bm, d) x (bn, d) -> (bm, bn) f32 inner products at full f32
+    precision: the engine's certificate bounds the screen error by the f32
+    matmul term, which a single bf16 MXU pass would exceed."""
+    return jax.lax.dot_general(
+        q, x, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _row_spec(block_n: int) -> pl.BlockSpec:
+    """Per-candidate vectors (norms, scales) ride as lane-major (1, n)
+    rows: the TPU compiler tiles a 1-D operand with XLA's own 1-D tiling,
+    which a (block_n,) block matches only when it spans the whole array."""
+    return pl.BlockSpec((1, block_n), lambda i, j: (0, j))
 
 
 @functools.partial(
@@ -221,6 +230,25 @@ def topk_ed_pallas(
     )(q, x)
 
 
+def _slate_specs(block_m: int, k: int) -> list:
+    """Output blocks of the fused screens: the (bm, k) slate pair plus the
+    |q|^2 column, kept 2-D as (bm, 1) for the same tiling reason as
+    :func:`_row_spec`."""
+    return [
+        pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
+    ]
+
+
+def _slate_shapes(m: int, k: int) -> list:
+    return [
+        jax.ShapeDtypeStruct((m, k), jnp.float32),
+        jax.ShapeDtypeStruct((m, k), jnp.int32),
+        jax.ShapeDtypeStruct((m, 1), jnp.float32),
+    ]
+
+
 @functools.partial(
     jax.jit, static_argnames=("k", "block_m", "block_n", "interpret")
 )
@@ -251,26 +279,19 @@ def screen_select_pallas(
     assert xn2.shape == (n,), (xn2.shape, n)
     assert 1 <= k <= n, (k, n)
     grid = (m // block_m, n // block_n)
-    return pl.pallas_call(
+    vals, idxs, qn2 = pl.pallas_call(
         functools.partial(_screen_select_body, k=k, block_n=block_n),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
+            _row_spec(block_n),
         ],
-        out_specs=[
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, k), jnp.float32),
-            jax.ShapeDtypeStruct((m, k), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
-        ],
+        out_specs=_slate_specs(block_m, k),
+        out_shape=_slate_shapes(m, k),
         interpret=interpret,
-    )(q, x, xn2)
+    )(q, x, xn2.reshape(1, n))
+    return vals, idxs, qn2[:, 0]
 
 
 @functools.partial(
@@ -302,27 +323,20 @@ def screen_select_quant_pallas(
     assert xn2.shape == (n,), (xn2.shape, n)
     assert 1 <= k <= n, (k, n)
     grid = (m // block_m, n // block_n)
-    return pl.pallas_call(
+    vals, idxs, qn2 = pl.pallas_call(
         functools.partial(_screen_select_quant_body, k=k, block_n=block_n),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
+            _row_spec(block_n),
+            _row_spec(block_n),
         ],
-        out_specs=[
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_m,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, k), jnp.float32),
-            jax.ShapeDtypeStruct((m, k), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
-        ],
+        out_specs=_slate_specs(block_m, k),
+        out_shape=_slate_shapes(m, k),
         interpret=interpret,
-    )(q, x, scale, xn2)
+    )(q, x, scale.reshape(1, n), xn2.reshape(1, n))
+    return vals, idxs, qn2[:, 0]
 
 
 @functools.partial(

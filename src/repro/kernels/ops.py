@@ -1,13 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles shape padding to block multiples, breakpoint tables, and backend
-dispatch: on TPU the kernels run compiled; elsewhere (this CPU container)
-they run in interpret mode, executing the same kernel bodies in Python —
-the validation mode mandated for this repro.
+dispatch: on TPU the kernels run compiled; on any other backend they run
+in interpret mode, executing the same kernel bodies in Python (how the
+test suite validates them on CPU).
 """
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -25,13 +23,8 @@ from .lb_kernel import mindist_pallas
 from .paa_kernel import paa_pallas
 from .sax_pack_kernel import sax_pack_pallas
 
-# Compiled on TPU, interpret mode elsewhere. REPRO_PALLAS_COMPILED=1 is the
-# compiled-mode validation escape: it forces interpret=False even off-TPU so
-# the kernels' real Mosaic lowering is exercised wherever an accelerator is
-# attached; tests/test_pallas_compiled.py wraps it with a graceful skip on
-# backends that cannot compile the kernels (the CI leg is allowed to skip).
-INTERPRET = (jax.default_backend() != "tpu"
-             and os.environ.get("REPRO_PALLAS_COMPILED") != "1")
+# compiled on TPU, interpret mode everywhere else
+INTERPRET = jax.default_backend() != "tpu"
 
 # sentinel |x|^2 for pad candidates: dominates any real screened distance
 # without overflowing the f32 d2 arithmetic (see screen_select)

@@ -1,8 +1,9 @@
 """Pallas kernel: PAA segment means, (B, n) -> (B, w).
 
 The summarization front of the Coconut ingest path. One grid step loads a
-(block_b, n) tile of raw series into VMEM, reduces each of the w segments
-with a reshape-mean (VPU), and writes the (block_b, w) summary tile.
+(block_b, n) tile of raw series into VMEM, reduces the w segments with one
+(n, w) averaging contraction (MXU), and writes the (block_b, w) summary
+tile. A 3-D reshape of the tile into segments has no TPU vector layout.
 
 Tiling: n is the series length (<= 1024 in practice); block_b is chosen so
 the tile fits comfortably in VMEM (block_b * n * 4B <= ~2 MiB), with the
@@ -19,9 +20,16 @@ from jax.experimental import pallas as pl
 
 def _paa_body(x_ref, o_ref, *, n_segments: int):
     x = x_ref[...].astype(jnp.float32)  # (bb, n)
-    bb, n = x.shape
+    n = x.shape[1]
     seg = n // n_segments
-    o_ref[...] = x.reshape(bb, n_segments, seg).mean(axis=-1)
+    # avg[i, s] = 1/seg where sample i lies in segment s
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n_segments), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n_segments), 1)
+    avg = jnp.where(row // seg == col, 1.0 / seg, 0.0).astype(jnp.float32)
+    o_ref[...] = jax.lax.dot_general(
+        x, avg, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("n_segments", "block_b", "interpret"))

@@ -19,7 +19,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from ..configs import ARCH_IDS, SHAPES, cell_is_skipped, get_config
 from ..core.distributed import DistBuildConfig, build_local, query_local
 from ..core.summarization import SummarizationConfig
@@ -345,7 +344,7 @@ def lower_coconut(cell: str, multi_pod: bool) -> dict:
             out_specs["series"] = P(axes)
 
         def build(series, ids):
-            f = shard_map(
+            f = jax.shard_map(
                 functools.partial(build_local, cfg=dcfg, axis_names=tuple(axes)),
                 mesh=mesh, in_specs=(P(axes), P(axes)),
                 out_specs=out_specs,
@@ -362,7 +361,7 @@ def lower_coconut(cell: str, multi_pod: bool) -> dict:
         rn = n_dev * cap * n_dev  # global rows of the exchanged index
 
         def query(index, queries):
-            f = shard_map(
+            f = jax.shard_map(
                 functools.partial(
                     query_local, cfg=dcfg, axis_names=tuple(axes),
                     k=spec["k"], verify_budget=spec["verify_budget"],

@@ -11,20 +11,19 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-from ..compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n: int | None = None, name: str = "data"):
     """A 1-D mesh over whatever devices exist (tests / CPU smoke)."""
     n = n or jax.device_count()
-    return make_mesh((n,), (name,))
+    return jax.make_mesh((n,), (name,), axis_types=(AxisType.Auto,))
 
 
 def dp_axes(multi_pod: bool) -> tuple:

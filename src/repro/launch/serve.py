@@ -393,4 +393,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from .jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import tree_flatten_with_path
 from .mesh import dp_axes
 
 
@@ -77,7 +76,7 @@ def leaf_spec(path_names: list[str], shape: tuple, mesh) -> P:
 
 def param_specs(abstract_params, mesh):
     """PartitionSpec pytree matching the (abstract) param tree."""
-    flat, treedef = tree_flatten_with_path(abstract_params)
+    flat, treedef = jax.tree.flatten_with_path(abstract_params)
     specs = []
     for path, leaf in flat:
         names = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]
@@ -143,7 +142,7 @@ def cache_specs(abstract_cache, mesh, multi_pod: bool):
             spec = [None] + spec
         return P(*spec)
 
-    flat, treedef = tree_flatten_with_path(abstract_cache)
+    flat, treedef = jax.tree.flatten_with_path(abstract_cache)
     return jax.tree.unflatten(treedef, [one(p, l) for p, l in flat])
 
 

@@ -23,7 +23,6 @@ import numpy as np
 
 import jax
 
-from ..compat import tree_flatten_with_path, tree_unflatten
 
 # numpy can't save/cast bfloat16 natively; store as uint16 bit patterns
 _WIDE = {"bfloat16": (ml_dtypes.bfloat16, np.uint16)}
@@ -43,7 +42,7 @@ def _from_storable(arr: np.ndarray, dtype_name: str) -> np.ndarray:
 
 
 def _flatten_with_paths(tree):
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         name = "_".join(
@@ -128,7 +127,7 @@ def restore(ckpt_dir: str, step: int, like: Any, *, shardings: Any = None) -> tu
             )
         arr = arr.astype(ref_leaf.dtype)
         out.append(jax.device_put(arr, sh) if sh is not None else jax.device_put(arr))
-    return tree_unflatten(treedef, out), manifest.get("extra", {})
+    return jax.tree.unflatten(treedef, out), manifest.get("extra", {})
 
 
 def restore_latest(ckpt_dir: str, like: Any, *, shardings: Any = None):

@@ -14,11 +14,11 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core import SummarizationConfig, ed2
 from repro.core.distributed import DistBuildConfig, make_build_fn, make_query_fn
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 scfg = SummarizationConfig(series_len=64, n_segments=8, card_bits=8)
 cfg = DistBuildConfig(summarization=scfg, capacity_slack=3.0)
 rng = np.random.default_rng(0)

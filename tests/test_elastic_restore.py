@@ -16,19 +16,20 @@ import os, sys, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.train import checkpoint as ckpt
 
 ckdir = tempfile.mkdtemp()
 
 # "cluster A": 4 devices (2x2 mesh), params sharded (data, model)
-mesh_a = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh_a = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4],
+                       axis_types=(AxisType.Auto,) * 2)
 w = jnp.arange(64 * 64, dtype=jnp.float32).reshape(64, 64)
 w_a = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
 ckpt.save(ckdir, 7, {"w": w_a})
 
 # "cluster B": all 8 devices (8x1), different sharding
-mesh_b = make_mesh((8,), ("data",))
+mesh_b = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 sh_b = {"w": NamedSharding(mesh_b, P("data", None))}
 like = {"w": jax.ShapeDtypeStruct((64, 64), jnp.float32)}
 restored, _ = ckpt.restore(ckdir, 7, like, shardings=sh_b)

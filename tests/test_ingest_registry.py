@@ -73,6 +73,25 @@ def test_registry_take_preserves_fifo_ids():
         np.concatenate([c.ids for c in snap.buffer]), np.arange(7, 10))
 
 
+def test_registry_take_copies_only_what_it_takes():
+    """A flush takes whole chunks and the head of one more; the rest of the
+    backlog stays as it was, so a large async backlog costs no copies."""
+    reg = RunRegistry()
+    chunks = [_chunk(5, seed=s, t=s, id0=5 * s) for s in range(4)]
+    for c in chunks:
+        reg.append_buffer(c)
+    taken, snap = reg.take_for_flush(7)
+    np.testing.assert_array_equal(taken.ids, np.arange(7))
+    np.testing.assert_array_equal(taken.ts, np.concatenate(
+        [chunks[0].ts, chunks[1].ts[:2]]))
+    np.testing.assert_array_equal(taken.series, np.concatenate(
+        [chunks[0].series, chunks[1].series[:2]]))
+    assert snap.buffer[1] is chunks[2] and snap.buffer[2] is chunks[3]
+    np.testing.assert_array_equal(
+        np.concatenate([c.ids for c in snap.buffer]), np.arange(7, 20))
+    np.testing.assert_array_equal(snap.buffer[0].ts, chunks[1].ts[2:])
+
+
 class _FakeRun:
     """A run stand-in that records arena releases."""
 

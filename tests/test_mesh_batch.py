@@ -16,7 +16,7 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.core import (CTree, CTreeConfig, RawStore, StreamConfig,
                         StreamingIndex, SummarizationConfig, ed2)
 from repro.core.distributed import (DistBuildConfig, default_batch_mesh,
@@ -73,7 +73,7 @@ for i in range(9):
     np.testing.assert_allclose(v1[i], bf, rtol=1e-5)
 
 # --- 3. composes with the sample-sorted distributed build -----------------
-mesh1d = make_mesh((8,), ("data",))
+mesh1d = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 dcfg = DistBuildConfig(summarization=SummarizationConfig(64, 8, 8),
                        capacity_slack=3.0)
 N = 8 * 256

@@ -1,11 +1,14 @@
-"""Compiled-mode Pallas validation (the ``REPRO_PALLAS_COMPILED=1`` CI leg).
+"""Ahead-of-time compiles of the served kernels for a described TPU v5e.
 
-Everywhere else the suite runs the Pallas kernels in interpret mode (the
-XLA twin serves the hot path off-TPU); this file is the one place that
-launches them through the REAL Mosaic lowering pipeline, so TPU-breaking
-kernel edits are caught by an opt-in leg instead of a TPU deploy. Off-TPU
-the lowering itself is expected to be unavailable: each test skips
-gracefully when compilation raises, and the leg is allowed-to-skip in CI.
+The rest of the suite runs the Pallas kernels in interpret mode on the CPU,
+which never asks the TPU compiler whether it accepts them. These tests
+compile, with no chip attached, the verification engine's fused passes
+through their TPU branch, the PAA kernel and the mesh top-k for a
+``v5e:2x2`` topology, so an edit the TPU compiler refuses fails here.
+Nothing runs: ``chip_smoke.py`` checks the results on the chip.
+
+The topology is described in a module fixture, never at import: only one
+process at a time may load the TPU compiler's library.
 """
 import os
 
@@ -13,78 +16,141 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
-pytestmark = pytest.mark.skipif(
-    os.environ.get("REPRO_PALLAS_COMPILED") != "1",
-    reason="compiled-mode Pallas validation runs only under "
-           "REPRO_PALLAS_COMPILED=1")
+D = 256  # the served series length
+CAP = 1 << 22  # a device-resident arena: 2^22 rows
+S = 50 + 8  # slate width k + slack for the widest served k
+DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
 
-def _compiled(fn, *args, **kw):
-    """Run a kernel launch, skipping when the backend can't lower it."""
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    from jax.experimental import topologies
+
     try:
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-        return out
-    except Exception as e:  # noqa: BLE001 — lowering errors vary by backend
-        if jax.default_backend() == "tpu":
-            raise  # on real TPU hardware a failure is a kernel bug
-        pytest.skip(f"Pallas compiled lowering unavailable off-TPU: "
-                    f"{type(e).__name__}")
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-def test_interpret_flag_is_off():
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tpu_branch(topo):
+    """Trace the kernels' TPU branch, with the persistent compilation cache
+    off (a TPU executable cached here could not be read back without a
+    chip). Traces are dropped on both sides so that no CPU trace of a fused
+    pass is reused here and no TPU trace leaks into later CPU tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     from repro.kernels import ops
-    # the env var must actually flip the dispatch constant
-    assert ops.INTERPRET is False or jax.default_backend() == "tpu"
+
+    cache_was = jax.config.jax_enable_compilation_cache
+    interpret_was = ops.INTERPRET
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    ops.INTERPRET = False
+    jax.clear_caches()
+    yield
+    ops.INTERPRET = interpret_was
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    cc.reset_cache()
 
 
-def test_topk_ed_pallas_compiled_matches_oracle():
-    from repro.kernels import ref
-    from repro.kernels.ed_scan_kernel import topk_ed_pallas
-
-    rng = np.random.default_rng(0)
-    q = rng.standard_normal((8, 128)).astype(np.float32)
-    x = rng.standard_normal((512, 128)).astype(np.float32)
-    v, i = _compiled(topk_ed_pallas, q, x, 5, block_m=8, block_n=128,
-                     interpret=False)
-    rv, ri = ref.topk_ed_ref(q, x, 5)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
-    np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_screen_select_pallas_compiled_matches_oracle():
-    from repro.kernels import ref
-    from repro.kernels.ed_scan_kernel import screen_select_pallas
-
-    rng = np.random.default_rng(1)
-    q = rng.standard_normal((8, 128)).astype(np.float32)
-    x = rng.standard_normal((512, 128)).astype(np.float32)
-    xn2 = np.einsum("nd,nd->n", x, x).astype(np.float32)
-    v, i, qn2 = _compiled(screen_select_pallas, q, x, xn2, 7,
-                          block_m=8, block_n=128, interpret=False)
-    rv, ri, rqn2 = ref.screen_select_ref(q, x, xn2, 7)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
-    np.testing.assert_allclose(np.asarray(v), np.asarray(rv),
-                               rtol=1e-5, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(qn2), np.asarray(rqn2), rtol=1e-6)
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
 
 
-def test_screen_select_quant_pallas_compiled_matches_oracle():
-    from repro.kernels import ref
-    from repro.kernels.ed_scan_kernel import screen_select_quant_pallas
+def _arena(dtype, cap, sh):
+    table = _sds((cap, D), DTYPES[dtype], sh)
+    xn2 = _sds((cap,), jnp.float32, sh)
+    scale = _sds((cap,), jnp.float32, sh) if dtype == "int8" else None
+    return table, xn2, scale
 
-    rng = np.random.default_rng(2)
-    q = rng.standard_normal((8, 128)).astype(np.float32)
-    xf = rng.standard_normal((512, 128)).astype(np.float32)
-    amax = np.abs(xf).max(axis=1)
-    scale = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
-    x = np.clip(np.rint(xf / scale[:, None]), -127, 127).astype(np.int8)
-    deq = x.astype(np.float64) * scale[:, None]
-    xn2 = np.einsum("nd,nd->n", deq, deq).astype(np.float32)
-    v, i, qn2 = _compiled(screen_select_quant_pallas, q, x, scale, xn2, 7,
-                          block_m=8, block_n=128, interpret=False)
-    rv, ri, _ = ref.screen_select_quant_ref(q, x, scale, xn2, 7)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
-    np.testing.assert_allclose(np.asarray(v), np.asarray(rv),
-                               rtol=1e-5, atol=1e-3)
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize("rows", [1536, 65536])
+def test_fused_gather_pass_compiles(tpu_branch, one_chip, dtype, rows):
+    """A gather pass at a rung above MIN_DEVICE_CANDIDATES, from an arena
+    of 2^22 rows, at the engine's widest query chunk."""
+    from repro.core.verify_engine import _CHUNK_M, _fused_screen
+
+    table, xn2, scale = _arena(dtype, CAP, one_chip)
+    hlo = _compile(
+        lambda t, n2, sc, r, q: _fused_screen(t, n2, sc, r, q, S),
+        table, xn2, scale, _sds((rows,), jnp.int32, one_chip),
+        _sds((_CHUNK_M, D), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo  # the Pallas kernel, not the XLA twin
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+def test_fused_full_coverage_pass_compiles(tpu_branch, one_chip, dtype):
+    from repro.core.verify_engine import _CHUNK_M, _fused_screen_full
+
+    table, xn2, scale = _arena(dtype, CAP, one_chip)
+    hlo = _compile(
+        lambda t, n2, sc, msk, q: _fused_screen_full(t, n2, sc, msk, q, S),
+        table, xn2, scale, _sds((CAP,), jnp.bool_, one_chip),
+        _sds((_CHUNK_M, D), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
+@pytest.mark.parametrize("m,d,k", [(128, 128, 1), (256, 256, 10),
+                                   (8, 256, 50)])
+def test_screen_kernels_compile_beyond_chunk(tpu_branch, one_chip, dtype, m,
+                                             d, k):
+    """The kernels alone, at query batches past the engine's chunk and at
+    both served series lengths, so they do not rely on the chunk size."""
+    from repro.kernels import ops
+
+    n = 4096
+    q = _sds((m, d), jnp.float32, one_chip)
+    x = _sds((n, d), DTYPES[dtype], one_chip)
+    vec = _sds((n,), jnp.float32, one_chip)
+    if dtype == "int8":
+        hlo = _compile(
+            lambda q, x, sc, n2: ops.screen_select_quant(q, x, sc, n2, k + 8),
+            q, x, vec, vec)
+    else:
+        hlo = _compile(lambda q, x, n2: ops.screen_select(q, x, n2, k + 8),
+                       q, x, vec)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n,w", [(256, 16), (128, 16)])
+def test_paa_kernel_compiles(tpu_branch, one_chip, n, w):
+    from repro.core import SummarizationConfig
+    from repro.kernels import ops
+
+    cfg = SummarizationConfig(series_len=n, n_segments=w, card_bits=8)
+    hlo = _compile(lambda x: ops.paa(x, cfg),
+                   _sds((1000, n), jnp.float32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_mesh_topk_compiles_on_2x2(tpu_branch, topo):
+    """The ``shard="mesh"`` screen over the four chips: queries on one mesh
+    axis, candidates on the other, folded by one all-gather."""
+    from repro.core.distributed import _mesh_topk_fn
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("q", "r"),
+                axis_types=(AxisType.Auto,) * 2)
+    q = _sds((64, D), jnp.float32, NamedSharding(mesh, P("q")))
+    x = _sds((2, 1 << 20, D), jnp.float32, NamedSharding(mesh, P("r")))
+    hlo = _compile(_mesh_topk_fn(mesh, S), q, x)
+    assert "all-gather" in hlo
