@@ -70,11 +70,9 @@ def main(argv=None) -> int:
         help="where BENCH_<module>.json artifacts are written (repo root)")
     args = ap.parse_args(argv)
 
-    from . import (common, construction, kernels_bench, memory, query, roofline,
-                   serving, streaming)
+    from . import common, construction, memory, query, serving, streaming
 
-    mods = [construction, query, streaming, serving, memory, kernels_bench,
-            roofline]
+    mods = [construction, query, streaming, serving, memory]
     if args.only:
         wanted = set(args.only.split(","))
         mods = [m for m in mods if m.__name__.split(".")[-1] in wanted]

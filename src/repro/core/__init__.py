@@ -34,7 +34,20 @@ from .autotune import (
     AutoTuner, AutoTunerConfig, DecisionRecord, Knobs, WorkloadKey,
     knob_grid, workload_key,
 )
-from .gateway import Gateway, GatewayConfig, GatewayStats, Response, Ticket
+
+# the gateway serves through the device engine and so loads jax: it is
+# imported on first use, so importing the package keeps the numpy host
+# path jax-free
+_GATEWAY = ("Gateway", "GatewayConfig", "GatewayStats", "Response", "Ticket")
+
+
+def __getattr__(name):
+    if name in _GATEWAY:
+        from . import gateway
+
+        return getattr(gateway, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 __all__ = [
     "SummarizationConfig", "breakpoints", "paa", "sax", "sax_from_paa",

@@ -31,6 +31,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import span
 from .ctree import RawStore, state_to_list
 from .execute import execute
 from .io_model import DiskModel
@@ -450,7 +451,8 @@ class ADSIndex:
         slots are (inf, -1). ``shard="mesh"`` executes the plan on the
         device mesh."""
         Q = np.asarray(Q, np.float32)
-        plan = self.plan(Q, tier="exact", raw=raw, window=window)
+        with span("repro.plan", tier="exact", runs=1):
+            plan = self.plan(Q, tier="exact", raw=raw, window=window)
         (vals, gids), stats = execute(plan, Q, k, backend=backend, shard=shard,
                                       mesh=mesh)
         return vals, gids, stats
@@ -479,7 +481,8 @@ class ADSIndex:
         per-query ``blocks_visited``, physical shared ``entries_verified``.
         """
         Q = np.asarray(Q, np.float32)
-        plan = self.plan(Q, tier="approx", raw=raw, window=window)
+        with span("repro.plan", tier="approx", runs=1):
+            plan = self.plan(Q, tier="approx", raw=raw, window=window)
         (vals, gids), stats = execute(plan, Q, k, backend=backend)
         return vals, gids, stats
 

@@ -32,6 +32,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import span
 from .ctree import RawStore, SortedRun, state_to_list
 from .execute import execute
 from .io_model import DiskModel
@@ -110,36 +111,37 @@ class CLSM:
         n = min(self.cfg.buffer_entries, self.registry.current().buffer_n)
         if n == 0:
             return
-        chunk, _ = self.registry.take_for_flush(n)
-        if chunk is None:
-            return
-        st = self.storage
-        if st is not None:
-            st.maybe_crash("flush-taken")
-        run, _ = SortedRun.build(
-            chunk.series,
-            chunk.ids,
-            self.cfg.summarization,
-            block_size=self.cfg.block_size,
-            materialized=self.cfg.materialized,
-            ts=chunk.ts,
-            disk=self.disk,
-            mem_budget_entries=self.cfg.buffer_entries,
-            screen_dtype=self.cfg.screen_dtype,
-        )
-        if st is not None:
-            # persist BEFORE publish: once queries can route to the run its
-            # files exist; the manifest commit below makes them the durable
-            # home of these entries (until then the WAL still covers them)
-            run = st.persist_run(run)
-        # queries planned while the run was sorting saw the chunk as a dense
-        # source; this single swap makes later plans see the run instead
-        snap = self.registry.publish_flush(chunk, run)
-        if st is not None:
-            st.commit_flush(chunk.n, snap)
-        self.n_flushes += 1
-        if self.cfg.merge:
-            self._maybe_merge(0)
+        with span("repro.ingest.flush", rows=n):
+            chunk, _ = self.registry.take_for_flush(n)
+            if chunk is None:
+                return
+            st = self.storage
+            if st is not None:
+                st.maybe_crash("flush-taken")
+            run, _ = SortedRun.build(
+                chunk.series,
+                chunk.ids,
+                self.cfg.summarization,
+                block_size=self.cfg.block_size,
+                materialized=self.cfg.materialized,
+                ts=chunk.ts,
+                disk=self.disk,
+                mem_budget_entries=self.cfg.buffer_entries,
+                screen_dtype=self.cfg.screen_dtype,
+            )
+            if st is not None:
+                # persist BEFORE publish: once queries can route to the run its
+                # files exist; the manifest commit below makes them the durable
+                # home of these entries (until then the WAL still covers them)
+                run = st.persist_run(run)
+            # queries planned while the run was sorting saw the chunk as a dense
+            # source; this single swap makes later plans see the run instead
+            snap = self.registry.publish_flush(chunk, run)
+            if st is not None:
+                st.commit_flush(chunk.n, snap)
+            self.n_flushes += 1
+            if self.cfg.merge:
+                self._maybe_merge(0)
 
     def flush_all(self) -> None:
         while self.registry.current().buffer_n > 0:
@@ -174,29 +176,30 @@ class CLSM:
     def _merge_runs(self, runs: list[SortedRun]) -> SortedRun:
         """Sort-merge runs (sequential read of inputs + sequential write)."""
         scfg = self.cfg.summarization
-        syms = np.concatenate([r.sax for r in runs])
-        ids = np.concatenate([r.ids for r in runs])
-        ts = np.concatenate([r.ts for r in runs]) if runs[0].ts is not None else None
-        series = (
-            np.concatenate([r.series for r in runs]) if runs[0].materialized else None
-        )
-        in_bytes = sum(r.index_bytes() for r in runs)
-        self.disk.read_seq(in_bytes)
-        merged, _ = SortedRun.from_arrays(
-            scfg,
-            syms,
-            ids,
-            block_size=self.cfg.block_size,
-            series=series,
-            ts=ts,
-            disk=None,  # accounted below as one sequential write
-            mem_budget_entries=max(1, self.cfg.buffer_entries),
-            screen_dtype=self.cfg.screen_dtype,
-        )
-        self.disk.write_seq(merged.index_bytes())
-        self.n_merges += 1
-        self.merged_bytes += in_bytes
-        return merged
+        with span("repro.ingest.merge", rows=sum(r.n for r in runs)):
+            syms = np.concatenate([r.sax for r in runs])
+            ids = np.concatenate([r.ids for r in runs])
+            ts = np.concatenate([r.ts for r in runs]) if runs[0].ts is not None else None
+            series = (
+                np.concatenate([r.series for r in runs]) if runs[0].materialized else None
+            )
+            in_bytes = sum(r.index_bytes() for r in runs)
+            self.disk.read_seq(in_bytes)
+            merged, _ = SortedRun.from_arrays(
+                scfg,
+                syms,
+                ids,
+                block_size=self.cfg.block_size,
+                series=series,
+                ts=ts,
+                disk=None,  # accounted below as one sequential write
+                mem_budget_entries=max(1, self.cfg.buffer_entries),
+                screen_dtype=self.cfg.screen_dtype,
+            )
+            self.disk.write_seq(merged.index_bytes())
+            self.n_merges += 1
+            self.merged_bytes += in_bytes
+            return merged
 
     # ---------------------------------------------------------------- query
     def _pinned(self, snapshot: Optional[RunSet]):
@@ -302,8 +305,9 @@ class CLSM:
         Returns ((m, k) d2, (m, k) ids, stats)."""
         Q = np.asarray(Q, np.float32)
         with self._pinned(snapshot) as snap:
-            plan = self.plan(Q, tier="exact", raw=raw, window=window,
-                             time_skip=time_skip, snapshot=snap)
+            with span("repro.plan", tier="exact", runs=snap.n_runs):
+                plan = self.plan(Q, tier="exact", raw=raw, window=window,
+                                 time_skip=time_skip, snapshot=snap)
             (vals, gids), stats = execute(plan, Q, k, backend=backend,
                                           shard=shard, mesh=mesh)
         return vals, gids, stats
@@ -335,9 +339,10 @@ class CLSM:
         (m, k) ids, stats)."""
         Q = np.asarray(Q, np.float32)
         with self._pinned(snapshot) as snap:
-            plan = self.plan(Q, tier="approx", n_blocks=n_blocks, raw=raw,
-                             window=window, time_skip=time_skip,
-                             backend=backend, snapshot=snap)
+            with span("repro.plan", tier="approx", runs=snap.n_runs):
+                plan = self.plan(Q, tier="approx", n_blocks=n_blocks, raw=raw,
+                                 window=window, time_skip=time_skip,
+                                 backend=backend, snapshot=snap)
             (vals, gids), stats = execute(plan, Q, k, backend=backend)
         return vals, gids, stats
 

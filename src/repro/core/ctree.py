@@ -35,6 +35,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import span
 from .execute import (
     BACKENDS,
     empty_topk_state,
@@ -98,11 +99,12 @@ class RawStore:
     def _all(self) -> np.ndarray:
         with self._lock:
             if self._data is None:
-                self._data = (
-                    np.concatenate(self._chunks, axis=0)
-                    if self._chunks
-                    else np.zeros((0, self.series_len), np.float32)
-                )
+                with span("repro.raw.concat", rows=self.n):
+                    self._data = (
+                        np.concatenate(self._chunks, axis=0)
+                        if self._chunks
+                        else np.zeros((0, self.series_len), np.float32)
+                    )
             return self._data
 
     def fetch(self, ids: np.ndarray) -> np.ndarray:
@@ -845,7 +847,8 @@ class CTree:
         ``shard="mesh"`` executes on the device mesh (queries x runs 2-D
         ``shard_map``) with host f64 re-ranking — same answers."""
         Q = np.asarray(Q, np.float32)
-        plan = self.plan(Q, tier="exact", raw=raw, window=window)
+        with span("repro.plan", tier="exact", runs=int(self.run is not None)):
+            plan = self.plan(Q, tier="exact", raw=raw, window=window)
         (vals, gids), stats = execute(plan, Q, k, backend=backend, shard=shard,
                                       mesh=mesh)
         return vals, gids, stats
@@ -873,8 +876,9 @@ class CTree:
         if backend not in BACKENDS:
             raise ValueError(f"unknown batch verify backend {backend!r}")
         Q = np.asarray(Q, np.float32)
-        plan = self.plan(Q, tier="approx", n_blocks=n_blocks, raw=raw,
-                         window=window, backend=backend)
+        with span("repro.plan", tier="approx", runs=int(self.run is not None)):
+            plan = self.plan(Q, tier="approx", n_blocks=n_blocks, raw=raw,
+                             window=window, backend=backend)
         (vals, gids), stats = execute(plan, Q, k, backend=backend)
         return vals, gids, stats
 

@@ -41,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import span
 from .io_model import coalesce_ranges
 from .lower_bounds import mindist_paa_sax2
 from .plan import (
@@ -249,10 +250,11 @@ def _account_fetch(ops, pos: np.ndarray) -> None:
     """Modeled-I/O accounting for a device-verified pass: the engine reads
     the arena, not the store, but serving still pays the host engine's
     modeled I/O so stats and heat maps stay comparable."""
-    if ops.fetch_account is not None:
-        ops.fetch_account(pos)
-    elif ops.fetch is not None:  # pragma: no cover - plumbing gap fallback
-        ops.fetch(pos)
+    with span("repro.execute.account"):
+        if ops.fetch_account is not None:
+            ops.fetch_account(pos)
+        elif ops.fetch is not None:  # pragma: no cover - plumbing gap fallback
+            ops.fetch(pos)
 
 
 def _device_topk(
@@ -326,18 +328,23 @@ def execute(
     if m == 0:
         return (vals, ids), stats
     if shard == "mesh":
-        return _execute_mesh(plan, Q, k, vals, ids, stats, mesh)
+        with span("repro.execute.mesh", m=m):
+            return _execute_mesh(plan, Q, k, vals, ids, stats, mesh)
     for src in plan.sources:
         if isinstance(src, DenseSource):
-            vals, ids = _exec_dense(src, plan, Q, k, vals, ids)
+            with span("repro.execute.dense", m=m):
+                vals, ids = _exec_dense(src, plan, Q, k, vals, ids)
         elif isinstance(src, BlockSource):
-            vals, ids = _exec_blocks(
-                src, plan, Q, k, vals, ids, stats, backend, blocks_per_round
-            )
+            with span("repro.execute.blocks", m=m):
+                vals, ids = _exec_blocks(
+                    src, plan, Q, k, vals, ids, stats, backend, blocks_per_round
+                )
         elif isinstance(src, RangeSource):
-            vals, ids = _exec_range(src, plan, Q, k, vals, ids, stats, backend)
+            with span("repro.execute.range", m=m):
+                vals, ids = _exec_range(src, plan, Q, k, vals, ids, stats, backend)
         elif isinstance(src, GroupSource):
-            vals, ids = _exec_group(src, plan, Q, k, vals, ids, stats, backend)
+            with span("repro.execute.group", m=m):
+                vals, ids = _exec_group(src, plan, Q, k, vals, ids, stats, backend)
         else:  # pragma: no cover - plan builder bug
             raise TypeError(f"unknown plan source {type(src).__name__}")
     return (vals, ids), stats
@@ -434,38 +441,41 @@ def _exec_blocks(src: BlockSource, plan, Q, k, vals, ids, stats, backend,
         nonlocal vals, ids
         done[sel] = True
         pos = np.concatenate([blocks[b] for b in sel])
-        if ops.index_read is not None:
-            ops.index_read(pos)
-        win = window_mask(ops.ts, plan.window, pos)
-        if win is not None:
-            stats.entries_pruned += int((~win).sum())
-            pos = pos[win]
-        if pos.size and qp is not None:
-            # entry-level MINDIST screen vs every query's current radius:
-            # an entry is fetched only if it could still improve someone
-            elb = mindist_paa_sax2(
-                qp[:, None, :], ops.sax[pos].astype(np.int64), ops.scfg
-            )  # (m, u)
-            keep = (elb < vals[:, -1][:, None]).any(axis=0)
-            stats.entries_pruned += int((~keep).sum())
-            pos = pos[keep]
-        if pos.size == 0:
-            return
-        stats.entries_verified += int(pos.size)
-        if _device_ready(ops, pos.size, backend, Q.shape[0]):
-            # ONE fused arena pass (gather + screen + in-kernel select);
-            # only the certified slate comes back for the f64 re-rank
-            _account_fetch(ops, pos)
-            nv, gids = _device_topk(Q, ops, pos, k, exact=True)
-        else:
-            data = ops.fetch(pos)
-            if backend == "kernel":
-                # ONE all-pairs topk_ed Pallas launch per (source, batch, pass)
-                nv, ni = _kernel_topk_dists(Q, data, k)
+        with span("repro.execute.round", blocks=int(sel.size),
+                  rows=int(pos.size)):
+            if ops.index_read is not None:
+                ops.index_read(pos)
+            win = window_mask(ops.ts, plan.window, pos)
+            if win is not None:
+                stats.entries_pruned += int((~win).sum())
+                pos = pos[win]
+            if pos.size and qp is not None:
+                # entry-level MINDIST screen vs every query's current radius:
+                # an entry is fetched only if it could still improve someone
+                elb = mindist_paa_sax2(
+                    qp[:, None, :], ops.sax[pos].astype(np.int64), ops.scfg
+                )  # (m, u)
+                keep = (elb < vals[:, -1][:, None]).any(axis=0)
+                stats.entries_pruned += int((~keep).sum())
+                pos = pos[keep]
+            if pos.size == 0:
+                return
+            stats.entries_verified += int(pos.size)
+            if _device_ready(ops, pos.size, backend, Q.shape[0]):
+                # ONE fused arena pass (gather + screen + in-kernel select);
+                # only the certified slate comes back for the f64 re-rank
+                _account_fetch(ops, pos)
+                nv, gids = _device_topk(Q, ops, pos, k, exact=True)
             else:
-                nv, ni = _screen_topk_exact(Q, data, k)
-            gids = np.where(ni >= 0, ops.ids[pos][np.maximum(ni, 0)], -1)
-        vals, ids = merge_topk_state(vals, ids, nv, gids)
+                with span("repro.execute.host_screen", rows=int(pos.size)):
+                    data = ops.fetch(pos)
+                    if backend == "kernel":
+                        # ONE all-pairs topk_ed Pallas launch per (source, batch, pass)
+                        nv, ni = _kernel_topk_dists(Q, data, k)
+                    else:
+                        nv, ni = _screen_topk_exact(Q, data, k)
+                gids = np.where(ni >= 0, ops.ids[pos][np.maximum(ni, 0)], -1)
+            vals, ids = merge_topk_state(vals, ids, nv, gids)
 
     # seed pass: every active query's single best-bounded block — tightens
     # all radii with one small shared verification
@@ -602,17 +612,18 @@ def _exec_range(src: RangeSource, plan, Q, k, vals, ids, stats, backend):
             rows = hmap[j0:j1]
             sub = data_h[rows]
             gid = gid_h[rows]
-        if backend == "kernel":
-            nv, ni = _kernel_topk_dists(Q[qidx], sub, k)
-            gi = np.where(ni >= 0, gid[np.maximum(ni, 0)], -1)
-        else:
-            if contiguous:
-                xsq_g = (ops.norms2(np.arange(glo, ghi))
-                         if ops.norms2 is not None else None)
+        with span("repro.execute.host_screen", rows=j1 - j0):
+            if backend == "kernel":
+                nv, ni = _kernel_topk_dists(Q[qidx], sub, k)
+                gi = np.where(ni >= 0, gid[np.maximum(ni, 0)], -1)
             else:
-                xsq_g = None if xsq_h is None else xsq_h[rows]
-            nv, ni = _screen_topk_slack(Q[qidx], sub, k, xsq=xsq_g)
-            gi = gid[ni]
+                if contiguous:
+                    xsq_g = (ops.norms2(np.arange(glo, ghi))
+                             if ops.norms2 is not None else None)
+                else:
+                    xsq_g = None if xsq_h is None else xsq_h[rows]
+                nv, ni = _screen_topk_slack(Q[qidx], sub, k, xsq=xsq_g)
+                gi = gid[ni]
         mv, mi = merge_topk_state(vals[qidx], ids[qidx], nv, gi)
         vals[qidx], ids[qidx] = mv, mi
     return vals, ids
@@ -640,13 +651,14 @@ def _exec_group(src: GroupSource, plan, Q, k, vals, ids, stats, backend):
             _account_fetch(ops, pos)
             nv, gi = _device_topk(Q[qidx], ops, pos, k, exact=False)
         else:  # small leaf groups take the host tail (same answers)
-            data = ops.fetch(pos)
-            if backend == "kernel":
-                nv, ni = _kernel_topk_dists(Q[qidx], data, k)
-                gi = np.where(ni >= 0, ops.ids[pos][np.maximum(ni, 0)], -1)
-            else:
-                nv, ni = _screen_topk_slack(Q[qidx], data, k)
-                gi = ops.ids[pos][ni]
+            with span("repro.execute.host_screen", rows=int(pos.size)):
+                data = ops.fetch(pos)
+                if backend == "kernel":
+                    nv, ni = _kernel_topk_dists(Q[qidx], data, k)
+                    gi = np.where(ni >= 0, ops.ids[pos][np.maximum(ni, 0)], -1)
+                else:
+                    nv, ni = _screen_topk_slack(Q[qidx], data, k)
+                    gi = ops.ids[pos][ni]
         mv, mi = merge_topk_state(vals[qidx], ids[qidx], nv, gi)
         vals[qidx], ids[qidx] = mv, mi
     return vals, ids

@@ -47,6 +47,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import install_gc_spans, span
 from .autotune import AutoTuner, AutoTunerConfig, Knobs, workload_key
 from .execute import recall_at_k
 from .recommender import Scenario, TierDecision, serving_tier
@@ -200,6 +201,7 @@ class Gateway:
             "batch_hist": {},  # formed (real) batch size -> count
             "shed_transitions": 0,  # enter/exit events of the shed state
         }
+        install_gc_spans()
         self._thread = threading.Thread(target=self._dispatch_loop,
                                         name="gateway-dispatch", daemon=True)
         self._thread.start()
@@ -300,11 +302,13 @@ class Gateway:
             formed = self._form_batch()
             if formed is None:
                 return
-            batch, shed_now = formed
+            batch, shed_now, seq = formed
             if not batch:
                 continue
             try:
-                self._serve_batch(batch, shed_now)
+                with span("repro.gateway.batch", batch=seq, size=len(batch),
+                          rung=_bucket_batch(len(batch))):
+                    self._serve_batch(batch, shed_now)
             except BaseException as e:  # resolve, or clients hang forever
                 for req in batch:
                     req.ticket._resolve(err=e)
@@ -312,31 +316,34 @@ class Gateway:
     def _form_batch(self):
         """Block until a batch is ready: either the top rung fills or the
         oldest request's deadline expires (then flush whatever is queued).
-        Returns None when closed and drained."""
+        Returns (batch, shed state, the batch's sequence number), or None
+        when closed and drained."""
         cfg = self.cfg
         with self._cond:
-            while not self._queue and not self._closed:
-                self._cond.wait()
-            if not self._queue:
-                return None  # closed and drained
-            deadline = self._queue[0].t_arrive + cfg.deadline_ms / 1e3
-            while len(self._queue) < cfg.max_batch and not self._closed:
-                rem = deadline - time.perf_counter()
-                if rem <= 0:
-                    break
-                self._cond.wait(rem)
+            with span("repro.gateway.wait"):
+                while not self._queue and not self._closed:
+                    self._cond.wait()
                 if not self._queue:
-                    return None if self._closed else ([], False)
+                    return None  # closed and drained
+                deadline = self._queue[0].t_arrive + cfg.deadline_ms / 1e3
+                while len(self._queue) < cfg.max_batch and not self._closed:
+                    rem = deadline - time.perf_counter()
+                    if rem <= 0:
+                        break
+                    self._cond.wait(rem)
+                    if not self._queue:
+                        return None if self._closed else ([], False, 0)
             take = min(len(self._queue), cfg.max_batch)
             batch = [self._queue.popleft() for _ in range(take)]
             self.stats["batches"] += 1
+            seq = self.stats["batches"]
             key = "full_flushes" if take >= cfg.max_batch else "deadline_flushes"
             self.stats[key] += 1
             hist = self.stats["batch_hist"]
             hist[take] = hist.get(take, 0) + 1
             shed_now = self._shedding
             self._cond.notify_all()  # free space for blocked submitters
-        return batch, shed_now
+        return batch, shed_now, seq
 
     def _route(self, req: _Request, shed_now: bool, *, epoch: int,
                n_series: int):

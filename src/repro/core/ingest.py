@@ -26,6 +26,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import span
 from .clsm import CLSM
 from .run_registry import BufferChunk
 
@@ -94,9 +95,10 @@ class IngestPipeline:
                 # a close() mid-wait still drains: wake on _done (worker
                 # exited), not on _stop alone, so a closing worker gets to
                 # shrink the backlog before we judge it stranded
-                self._cond.wait_for(
-                    lambda: self._done or self._error is not None
-                    or self._backlog() <= self.max_lag_entries)
+                with span("repro.ingest.blocked", rows=chunk.n):
+                    self._cond.wait_for(
+                        lambda: self._done or self._error is not None
+                        or self._backlog() <= self.max_lag_entries)
                 if (self._error is None and self._done
                         and self._backlog() > self.max_lag_entries):
                     # the worker exited while this insert waited on

@@ -13,9 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax.numpy as jnp
-
-from .summarization import SummarizationConfig
+from .summarization import SummarizationConfig, array_module
 
 
 def _bit_positions(cfg: SummarizationConfig) -> np.ndarray:
@@ -37,7 +35,7 @@ def interleave(sym, cfg: SummarizationConfig):
     returns: (..., n_words) uint32 key words, word 0 most significant,
              bit 31 of each word most significant. Unused low bits are 0.
     """
-    xp = jnp if isinstance(sym, jnp.ndarray) else np
+    xp = array_module(sym)
     w, c = cfg.n_segments, cfg.card_bits
     nw = cfg.key_words
     # bits of each symbol, MSB first: (..., c, w)
@@ -56,7 +54,7 @@ def interleave(sym, cfg: SummarizationConfig):
 
 def deinterleave(keys, cfg: SummarizationConfig):
     """Inverse of :func:`interleave`. keys: (..., n_words) uint32 -> (..., w) int32."""
-    xp = jnp if isinstance(keys, jnp.ndarray) else np
+    xp = array_module(keys)
     w, c = cfg.n_segments, cfg.card_bits
     nw = cfg.key_words
     shifts = xp.arange(31, -1, -1, dtype=xp.uint32)

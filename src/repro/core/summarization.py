@@ -13,10 +13,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 
 import numpy as np
 
-import jax.numpy as jnp
+
+def array_module(x):
+    """``jax.numpy`` for a jax array (a tracer included), else ``numpy``.
+    Asked without importing jax, so numpy callers stay jax-free."""
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(x, jax.Array):
+        return jax.numpy
+    return np
 
 
 def _ndtri(p: np.ndarray) -> np.ndarray:
@@ -117,7 +125,7 @@ def znormalize(x: np.ndarray, eps: float = 1e-6) -> np.ndarray:
 
 def paa(x: np.ndarray, cfg: SummarizationConfig) -> np.ndarray:
     """PAA segment means. x: (..., n) -> (..., w)."""
-    xp = jnp if isinstance(x, jnp.ndarray) else np
+    xp = array_module(x)
     if cfg.znorm:
         x = znormalize(x) if xp is np else (x - x.mean(-1, keepdims=True)) / (
             x.std(-1, keepdims=True) + 1e-6
@@ -129,9 +137,10 @@ def paa(x: np.ndarray, cfg: SummarizationConfig) -> np.ndarray:
 def sax_from_paa(p: np.ndarray, cfg: SummarizationConfig) -> np.ndarray:
     """Quantize PAA values into SAX symbols in [0, 2**c). p: (..., w)."""
     bps = breakpoints(cfg.card_bits)
-    if isinstance(p, jnp.ndarray):
+    xp = array_module(p)
+    if xp is not np:
         # symbol = number of breakpoints <= value
-        return jnp.sum(p[..., None] >= jnp.asarray(bps), axis=-1).astype(jnp.int32)
+        return xp.sum(p[..., None] >= xp.asarray(bps), axis=-1).astype(xp.int32)
     return np.searchsorted(bps, p, side="right").astype(np.int32)
 
 
@@ -150,7 +159,8 @@ def sax_region(sym: np.ndarray, cfg: SummarizationConfig):
     big = np.float32(1e30)
     lo = np.concatenate([[-big], bps]).astype(np.float32)
     hi = np.concatenate([bps, [big]]).astype(np.float32)
-    if isinstance(sym, jnp.ndarray):
-        lo, hi = jnp.asarray(lo), jnp.asarray(hi)
+    xp = array_module(sym)
+    if xp is not np:
+        lo, hi = xp.asarray(lo), xp.asarray(hi)
         return lo[sym], hi[sym]
     return lo[sym], hi[sym]

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops as kops
+from ..obs import span
 
 # passes smaller than this verify on the host: below the floor the launch
 # overhead rivals the whole NumPy screen, so the device path would lose
@@ -297,6 +298,8 @@ class VerifyEngine:
             "d2h_bytes": 0,  # device->host: downloaded slates
             "uploads": 0,  # arena builds/extends
             "fallbacks": 0,  # queries re-screened on host (cert failures)
+            "candidates": 0,  # candidate rows the passes asked for
+            "gathered_rows": 0,  # rows the passes screened: bucket or cap
             "released_arenas": 0,  # arenas retired by the run registry
             "released_bytes": 0,  # device bytes those arenas held
             "arena_bytes": 0,  # live device arena footprint (all dtypes)
@@ -309,46 +312,47 @@ class VerifyEngine:
                    dtype: Optional[str] = None) -> DeviceView:
         """Upload a table into a fresh bucketed arena (one h2d copy),
         optionally quantized to the requested storage dtype."""
-        sd = self.dtype if dtype in (None, "") else resolve_screen_dtype(dtype)
-        host_table = np.ascontiguousarray(host_table, np.float32)
-        n, d = host_table.shape
-        cap = _bucket_rows(n + 1)
-        mu = host_table.mean(axis=0).astype(np.float32) if n else np.zeros(
-            d, np.float32)
-        buf = np.zeros((cap, d), np.float32)
-        np.subtract(host_table, mu[None, :], out=buf[:n])
-        stored, rscale, vxn2, qerr = _quantize_rows(buf[:n], sd)
-        if sd == "f32":
-            tbl = buf  # zero tail already in place, no copy
-        else:
-            tbl = np.zeros((cap, d), _SCREEN_DTYPES[sd])
-            tbl[:n] = stored
-        xn2 = np.full(cap, kops.BIG_NORM2, np.float32)
-        xn2[:n] = vxn2
-        scale = None
-        if rscale is not None:
-            scale = np.ones(cap, np.float32)  # sentinel/pad rows: scale 1
-            scale[:n] = rscale
-        nbytes = tbl.nbytes + xn2.nbytes + (scale.nbytes if scale is not None
-                                            else 0)
-        view = DeviceView(
-            host=host_table,
-            mu=mu,
-            table=jax.device_put(tbl),
-            xn2=jax.device_put(xn2),
-            n=n,
-            cap=cap,
-            xn2max=float(vxn2.max()) if n else 0.0,
-            dtype=sd,
-            scale=None if scale is None else jax.device_put(scale),
-            qerr=qerr,
-            nbytes=nbytes,
-        )
-        with self._lock:
-            self.stats["uploads"] += 1
-            self.stats["h2d_bytes"] += nbytes
-            self.stats["arena_bytes"] += nbytes
-        return view
+        with span("repro.arena.build", rows=len(host_table)):
+            sd = self.dtype if dtype in (None, "") else resolve_screen_dtype(dtype)
+            host_table = np.ascontiguousarray(host_table, np.float32)
+            n, d = host_table.shape
+            cap = _bucket_rows(n + 1)
+            mu = host_table.mean(axis=0).astype(np.float32) if n else np.zeros(
+                d, np.float32)
+            buf = np.zeros((cap, d), np.float32)
+            np.subtract(host_table, mu[None, :], out=buf[:n])
+            stored, rscale, vxn2, qerr = _quantize_rows(buf[:n], sd)
+            if sd == "f32":
+                tbl = buf  # zero tail already in place, no copy
+            else:
+                tbl = np.zeros((cap, d), _SCREEN_DTYPES[sd])
+                tbl[:n] = stored
+            xn2 = np.full(cap, kops.BIG_NORM2, np.float32)
+            xn2[:n] = vxn2
+            scale = None
+            if rscale is not None:
+                scale = np.ones(cap, np.float32)  # sentinel/pad rows: scale 1
+                scale[:n] = rscale
+            nbytes = tbl.nbytes + xn2.nbytes + (scale.nbytes if scale is not None
+                                                else 0)
+            view = DeviceView(
+                host=host_table,
+                mu=mu,
+                table=jax.device_put(tbl),
+                xn2=jax.device_put(xn2),
+                n=n,
+                cap=cap,
+                xn2max=float(vxn2.max()) if n else 0.0,
+                dtype=sd,
+                scale=None if scale is None else jax.device_put(scale),
+                qerr=qerr,
+                nbytes=nbytes,
+            )
+            with self._lock:
+                self.stats["uploads"] += 1
+                self.stats["h2d_bytes"] += nbytes
+                self.stats["arena_bytes"] += nbytes
+            return view
 
     def extend_view(self, view: DeviceView, host_table: np.ndarray) -> DeviceView:
         """Grow an arena to cover an append-only table's new rows.
@@ -358,55 +362,56 @@ class VerifyEngine:
         rows, quantized to the arena's storage dtype and bucket-padded so
         steady streaming reuses one trace); overflowing arenas rebuild at
         the next bucket. Existing rows' int8 scales are never rewritten."""
-        n_new = host_table.shape[0]
-        if n_new <= view.n:
-            return view
-        grow = n_new - view.n
-        pad = _bucket_rows(grow) - grow  # bucket the chunk: stable traces
-        if n_new + pad + 1 > view.cap:
-            nv = self.build_view(host_table, dtype=view.dtype)
-            with self._lock:  # the overflowing arena is being replaced
-                self.stats["arena_bytes"] -= view.nbytes
-            return nv
-        chunk = np.zeros((grow + pad, host_table.shape[1]), np.float32)
-        np.subtract(host_table[view.n:], view.mu[None, :], out=chunk[:grow])
-        stored, rscale, vxn2, cqerr = _quantize_rows(chunk[:grow], view.dtype)
-        if view.dtype == "f32":
-            payload = chunk
-        else:
-            payload = np.zeros(chunk.shape, _SCREEN_DTYPES[view.dtype])
-            payload[:grow] = stored
-        cn2 = np.full(grow + pad, kops.BIG_NORM2, np.float32)
-        cn2[:grow] = vxn2
-        h2d = payload.nbytes + cn2.nbytes
-        if view.dtype == "int8":
-            cs = np.ones(grow + pad, np.float32)
-            cs[:grow] = rscale
-            h2d += cs.nbytes
-            table, xn2, scale = _arena_extend_quant(
-                view.table, view.xn2, view.scale, jnp.asarray(payload),
-                jnp.asarray(cn2), jnp.asarray(cs), np.int64(view.n))
-        else:
-            table, xn2 = _arena_extend(
-                view.table, view.xn2, jnp.asarray(payload), jnp.asarray(cn2),
-                np.int64(view.n))
-            scale = view.scale
-        with self._lock:
-            self.stats["uploads"] += 1
-            self.stats["h2d_bytes"] += h2d
-        return DeviceView(
-            host=np.ascontiguousarray(host_table, np.float32),
-            mu=view.mu,
-            table=table,
-            xn2=xn2,
-            n=n_new,
-            cap=view.cap,
-            xn2max=max(view.xn2max, float(vxn2.max())),
-            dtype=view.dtype,
-            scale=scale,
-            qerr=max(view.qerr, cqerr),
-            nbytes=view.nbytes,  # in-place: capacity (and footprint) fixed
-        )
+        with span("repro.arena.extend", rows=len(host_table)):
+            n_new = host_table.shape[0]
+            if n_new <= view.n:
+                return view
+            grow = n_new - view.n
+            pad = _bucket_rows(grow) - grow  # bucket the chunk: stable traces
+            if n_new + pad + 1 > view.cap:
+                nv = self.build_view(host_table, dtype=view.dtype)
+                with self._lock:  # the overflowing arena is being replaced
+                    self.stats["arena_bytes"] -= view.nbytes
+                return nv
+            chunk = np.zeros((grow + pad, host_table.shape[1]), np.float32)
+            np.subtract(host_table[view.n:], view.mu[None, :], out=chunk[:grow])
+            stored, rscale, vxn2, cqerr = _quantize_rows(chunk[:grow], view.dtype)
+            if view.dtype == "f32":
+                payload = chunk
+            else:
+                payload = np.zeros(chunk.shape, _SCREEN_DTYPES[view.dtype])
+                payload[:grow] = stored
+            cn2 = np.full(grow + pad, kops.BIG_NORM2, np.float32)
+            cn2[:grow] = vxn2
+            h2d = payload.nbytes + cn2.nbytes
+            if view.dtype == "int8":
+                cs = np.ones(grow + pad, np.float32)
+                cs[:grow] = rscale
+                h2d += cs.nbytes
+                table, xn2, scale = _arena_extend_quant(
+                    view.table, view.xn2, view.scale, jnp.asarray(payload),
+                    jnp.asarray(cn2), jnp.asarray(cs), np.int64(view.n))
+            else:
+                table, xn2 = _arena_extend(
+                    view.table, view.xn2, jnp.asarray(payload), jnp.asarray(cn2),
+                    np.int64(view.n))
+                scale = view.scale
+            with self._lock:
+                self.stats["uploads"] += 1
+                self.stats["h2d_bytes"] += h2d
+            return DeviceView(
+                host=np.ascontiguousarray(host_table, np.float32),
+                mu=view.mu,
+                table=table,
+                xn2=xn2,
+                n=n_new,
+                cap=view.cap,
+                xn2max=max(view.xn2max, float(vxn2.max())),
+                dtype=view.dtype,
+                scale=scale,
+                qerr=max(view.qerr, cqerr),
+                nbytes=view.nbytes,  # in-place: capacity (and footprint) fixed
+            )
 
     def release_view(self, view: DeviceView) -> None:
         """Retire an arena: the registry calls this once no pinned epoch
@@ -431,41 +436,47 @@ class VerifyEngine:
         their device work."""
         m = Qc.shape[0]
         mb = _bucket_batch(m)
-        qpad = np.zeros((mb, Qc.shape[1]), np.float32)
-        qpad[:m] = Qc
-        with self._lock:
-            self.stats["calls"] += 1
-            self.stats["screened"] += m
-            hist = self.stats["batch_hist"]
-            hist[mb] = hist.get(mb, 0) + 1
-            before = _TRACES[0]
-            bb = max(_bucket_rows(trows.size), _bucket_rows(s, 8))
-            if bb >= view.cap:
-                # full-coverage pass: the gathered bucket would be
-                # table-sized anyway, so screen the resident table through a
-                # candidate mask instead of materializing a table-sized
-                # gather
-                mask = np.zeros(view.cap, bool)
-                mask[trows] = True
-                self.stats["h2d_bytes"] += mask.nbytes + qpad.nbytes
-                vals, srows, invalid = _fused_screen_full(
-                    view.table, view.xn2, view.scale, jnp.asarray(mask),
-                    jnp.asarray(qpad), s)
-            else:
-                rows = np.full(bb, view.n, np.int32)  # pad: the sentinel row
-                rows[: trows.size] = trows
-                self.stats["h2d_bytes"] += rows.nbytes + qpad.nbytes
-                vals, srows, invalid = _fused_screen(
-                    view.table, view.xn2, view.scale, jnp.asarray(rows),
-                    jnp.asarray(qpad), s)
-            if _TRACES[0] == before:  # served from an already-compiled trace
-                self.stats["hits"] += 1
-            self.stats["traces"] = _TRACES[0]
+        bb = max(_bucket_rows(trows.size), _bucket_rows(s, 8))
+        # full-coverage pass: the gathered bucket would be table-sized
+        # anyway, so screen the resident table through a candidate mask
+        # instead of materializing a table-sized gather
+        full = bb >= view.cap
+        gathered = view.cap if full else bb
+        with span("repro.verify.launch", m=m, bucket=mb, rows=int(trows.size),
+                  gathered=gathered, full=int(full)):
+            qpad = np.zeros((mb, Qc.shape[1]), np.float32)
+            qpad[:m] = Qc
+            with self._lock:
+                self.stats["calls"] += 1
+                self.stats["screened"] += m
+                self.stats["candidates"] += int(trows.size)
+                self.stats["gathered_rows"] += gathered
+                hist = self.stats["batch_hist"]
+                hist[mb] = hist.get(mb, 0) + 1
+                before = _TRACES[0]
+                if full:
+                    mask = np.zeros(view.cap, bool)
+                    mask[trows] = True
+                    self.stats["h2d_bytes"] += mask.nbytes + qpad.nbytes
+                    vals, srows, invalid = _fused_screen_full(
+                        view.table, view.xn2, view.scale, jnp.asarray(mask),
+                        jnp.asarray(qpad), s)
+                else:
+                    rows = np.full(bb, view.n, np.int32)  # pad: the sentinel
+                    rows[: trows.size] = trows
+                    self.stats["h2d_bytes"] += rows.nbytes + qpad.nbytes
+                    vals, srows, invalid = _fused_screen(
+                        view.table, view.xn2, view.scale, jnp.asarray(rows),
+                        jnp.asarray(qpad), s)
+                if _TRACES[0] == before:  # served from a compiled trace
+                    self.stats["hits"] += 1
+                self.stats["traces"] = _TRACES[0]
         # jax dispatch is asynchronous: np.asarray blocks on the result, so
         # it must not run under the lock
-        vals = np.asarray(vals)[:m]
-        srows = np.asarray(srows)[:m].astype(np.int64)
-        invalid = np.asarray(invalid)[:m]
+        with span("repro.verify.wait"):
+            vals = np.asarray(vals)[:m]
+            srows = np.asarray(srows)[:m].astype(np.int64)
+            invalid = np.asarray(invalid)[:m]
         with self._lock:
             self.stats["d2h_bytes"] += (vals.nbytes + srows.nbytes
                                         + invalid.nbytes)
@@ -511,7 +522,8 @@ class VerifyEngine:
         s = min(k + _SLACK, u)
         Qc = np.asarray(Q, np.float32) - view.mu[None, :]
         v_screen, srows = self._launch(view, trows, Qc, s)
-        nv, nrows = _rerank_slate(Q, view.host, srows, k)
+        with span("repro.verify.rerank"):
+            nv, nrows = _rerank_slate(Q, view.host, srows, k)
         if s >= u:
             return nv, nrows  # the slate IS the candidate set: always exact
         # certificate: anything screened out of the slate has screen d2 >=
@@ -520,34 +532,36 @@ class VerifyEngine:
         # quantized arenas the screen ranks x_stored = x + e, |e| <= qerr,
         # which moves a distance by at most 2(|q| + |x|)|e| — widen the
         # bound by that term (qerr = 0 keeps the pure-f32 certificate).
-        qn = np.sqrt(np.einsum("mn,mn->m", Qc, Qc, dtype=np.float64))
-        xnmax = np.sqrt(max(view.xn2max, 0.0))
-        bound = (4.0 * Q.shape[1] * np.finfo(np.float32).eps * qn * xnmax)
-        if view.qerr > 0.0:
-            bound = bound + 2.0 * (qn + xnmax) * view.qerr
-        kk = min(k, u)
-        kth = nv[:, kk - 1] if nv.shape[1] >= kk else np.full(m, np.inf)
-        certified = (srows >= 0).all(axis=1) & (
-            np.where(np.isfinite(kth), kth, 0.0) <= v_screen[:, -1] - 2.0 * bound
-        )
-        bad = np.nonzero(~certified)[0]
+        with span("repro.verify.certify"):
+            qn = np.sqrt(np.einsum("mn,mn->m", Qc, Qc, dtype=np.float64))
+            xnmax = np.sqrt(max(view.xn2max, 0.0))
+            bound = (4.0 * Q.shape[1] * np.finfo(np.float32).eps * qn * xnmax)
+            if view.qerr > 0.0:
+                bound = bound + 2.0 * (qn + xnmax) * view.qerr
+            kk = min(k, u)
+            kth = nv[:, kk - 1] if nv.shape[1] >= kk else np.full(m, np.inf)
+            certified = (srows >= 0).all(axis=1) & (
+                np.where(np.isfinite(kth), kth, 0.0) <= v_screen[:, -1] - 2.0 * bound
+            )
+            bad = np.nonzero(~certified)[0]
         if bad.size:
-            with self._lock:
-                self.stats["fallbacks"] += int(bad.size)
-            if exact:
-                ev, er = _screen_topk_exact(Q[bad], view.host[trows], k)
-            else:  # approximate tiers keep their slack-screen semantics
-                from .execute import _screen_topk_slack
+            with span("repro.verify.fallback", queries=int(bad.size), rows=u):
+                with self._lock:
+                    self.stats["fallbacks"] += int(bad.size)
+                if exact:
+                    ev, er = _screen_topk_exact(Q[bad], view.host[trows], k)
+                else:  # approximate tiers keep their slack-screen semantics
+                    from .execute import _screen_topk_slack
 
-                ev, er = _screen_topk_slack(Q[bad], view.host[trows], k)
-            pad = nv.shape[1] - ev.shape[1]
-            if pad > 0:
-                ev = np.concatenate(
-                    [ev, np.full((bad.size, pad), np.inf, ev.dtype)], axis=1)
-                er = np.concatenate(
-                    [er, np.full((bad.size, pad), -1, er.dtype)], axis=1)
-            nv[bad] = ev
-            nrows[bad] = np.where(er >= 0, trows[np.maximum(er, 0)], -1)
+                    ev, er = _screen_topk_slack(Q[bad], view.host[trows], k)
+                pad = nv.shape[1] - ev.shape[1]
+                if pad > 0:
+                    ev = np.concatenate(
+                        [ev, np.full((bad.size, pad), np.inf, ev.dtype)], axis=1)
+                    er = np.concatenate(
+                        [er, np.full((bad.size, pad), -1, er.dtype)], axis=1)
+                nv[bad] = ev
+                nrows[bad] = np.where(er >= 0, trows[np.maximum(er, 0)], -1)
         return nv, nrows
 
     # ------------------------------------------------------------ warm-up
@@ -557,26 +571,28 @@ class VerifyEngine:
         (arena capacity, candidate bucket) at the serving batch/k shape and
         storage dtype, so steady-state traffic starts at zero retraces.
         Returns the number of traces compiled."""
-        sd = self.dtype if dtype in (None, "") else resolve_screen_dtype(dtype)
-        before = _TRACES[0]
-        s = k + _SLACK
-        mb = _bucket_batch(min(m, _CHUNK_M))
-        for cap in sorted({_bucket_rows(c + 1) for c in caps}):
-            table = jnp.zeros((cap, d), _SCREEN_DTYPES[sd])
-            xn2 = jnp.full((cap,), kops.BIG_NORM2, jnp.float32)
-            scale = (jnp.ones((cap,), jnp.float32) if sd == "int8" else None)
-            qc = jnp.zeros((mb, d), jnp.float32)
-            b = _bucket_rows(min(s, cap))
-            while b < cap:  # the gather ladder below full coverage
-                rows = jnp.zeros((b,), jnp.int32)
+        with span("repro.setup.prewarm") as sp:
+            sd = self.dtype if dtype in (None, "") else resolve_screen_dtype(dtype)
+            before = _TRACES[0]
+            s = k + _SLACK
+            mb = _bucket_batch(min(m, _CHUNK_M))
+            for cap in sorted({_bucket_rows(c + 1) for c in caps}):
+                table = jnp.zeros((cap, d), _SCREEN_DTYPES[sd])
+                xn2 = jnp.full((cap,), kops.BIG_NORM2, jnp.float32)
+                scale = (jnp.ones((cap,), jnp.float32) if sd == "int8" else None)
+                qc = jnp.zeros((mb, d), jnp.float32)
+                b = _bucket_rows(min(s, cap))
+                while b < cap:  # the gather ladder below full coverage
+                    rows = jnp.zeros((b,), jnp.int32)
+                    jax.block_until_ready(
+                        _fused_screen(table, xn2, scale, rows, qc, min(s, b)))
+                    b = _bucket_rows(b + 1)
+                mask = jnp.zeros((cap,), bool)  # the full-coverage variant
                 jax.block_until_ready(
-                    _fused_screen(table, xn2, scale, rows, qc, min(s, b)))
-                b = _bucket_rows(b + 1)
-            mask = jnp.zeros((cap,), bool)  # the full-coverage variant
-            jax.block_until_ready(
-                _fused_screen_full(table, xn2, scale, mask, qc, s))
-        with self._lock:
-            self.stats["traces"] = _TRACES[0]
+                    _fused_screen_full(table, xn2, scale, mask, qc, s))
+            with self._lock:
+                self.stats["traces"] = _TRACES[0]
+            sp.set_metadata(traces=_TRACES[0] - before)
         return _TRACES[0] - before
 
 _ENGINE: Optional[VerifyEngine] = None

@@ -227,6 +227,7 @@ def topk_ed_pallas(
             jax.ShapeDtypeStruct((m, k), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_ed_pallas",
     )(q, x)
 
 
@@ -290,6 +291,7 @@ def screen_select_pallas(
         out_specs=_slate_specs(block_m, k),
         out_shape=_slate_shapes(m, k),
         interpret=interpret,
+        name="screen_select_pallas",
     )(q, x, xn2.reshape(1, n))
     return vals, idxs, qn2[:, 0]
 
@@ -335,6 +337,7 @@ def screen_select_quant_pallas(
         out_specs=_slate_specs(block_m, k),
         out_shape=_slate_shapes(m, k),
         interpret=interpret,
+        name="screen_select_quant_pallas",
     )(q, x, scale.reshape(1, n), xn2.reshape(1, n))
     return vals, idxs, qn2[:, 0]
 
@@ -373,4 +376,5 @@ def min_ed_pallas(
             jax.ShapeDtypeStruct((m,), jnp.int32),
         ],
         interpret=interpret,
+        name="min_ed_pallas",
     )(q, x)

@@ -322,8 +322,11 @@ def test_concurrent_clients_with_background_ingest_parity():
                 assert (r.ids >= 0).all()  # k << live entries: full slates
                 by_batch.setdefault((r.epoch, r.batch_size,
                                      round(r.queue_wait_ms, 6)), 0)
-        # quiesced phase: ingest drained -> parity must be bitwise
+        # quiesced phase: ingest drained -> parity must be bitwise. A loaded
+        # host can push the live phase's rolling p99 past the SLO, and the
+        # shed state would serve the unmarked exact requests approximately
         idx.drain(timeout=120)
+        gw.reset_slo_window()
         Q = _series(10, 999)
         resps = [t.result(timeout=60) for t in
                  [gw.submit(q) for q in Q[:5]] +
