@@ -29,11 +29,12 @@ the compute units:
   certificate terms — dispatched to the :func:`screen_select_pallas`
   kernel on TPU and to its XLA twin elsewhere (the same compiled/interpret
   split as ``kernels.ops``; interpret-mode Pallas is a validation tool,
-  not a serving path). Only the tiny slate crosses back to the host.
+  not a serving path). Only the tiny slate crosses back to the host,
+  packed into one array: one device-to-host transfer per pass.
 * **Shape-bucketed compile cache**: candidate counts and query-batch sizes
   pad to power-of-two buckets, so steady-state serving executes from a
   handful of cached traces with ZERO retraces after warm-up. The engine
-  counts traces/hits, host<->device transfer bytes, and the live arena
+  counts traces/hits, host<->device transfer bytes and copies, the live arena
   footprint/storage dtype (:attr:`VerifyEngine.stats`), and
   :meth:`VerifyEngine.prewarm` compiles the bucket ladder up front.
 
@@ -245,12 +246,28 @@ def _screen_core(sub, n2, qc, s, scale=None):
     return -negv, pidx
 
 
+def _pack_slate(vals, srows, pidx):
+    """The slate as ONE (m, 2s) int32 array, so the host downloads it in a
+    single transfer (each blocking copy costs a fixed latency that dwarfs
+    the slate's bytes): the values' f32 bits, then the rows, -1 where the
+    select found no candidate. ``_unpack_slate`` undoes it bit-exactly."""
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(vals, jnp.int32),
+         jnp.where(pidx < 0, -1, srows)], axis=1)
+
+
+def _unpack_slate(slate: np.ndarray, s: int):
+    """Host side of ``_pack_slate``: (vals (m, s) f32, rows (m, s) int64)."""
+    return slate[:, :s].view(np.float32), slate[:, s:].astype(np.int64)
+
+
 @functools.partial(jax.jit, static_argnames=("s",))
 def _fused_screen(table, xn2, scale, rows, qc, s):
     """ONE device call per verification pass: gather the pass's candidate
     rows from the arena, screen them in f32 matmul form against the cached
     norms, and select the top-s slate in-kernel. Pad rows (index = the
-    sentinel row) carry BIG_NORM2 and never enter a slate."""
+    sentinel row) carry BIG_NORM2 and never enter a slate. Returns the
+    packed slate (``_pack_slate``)."""
     # trace-time-only execution is the POINT: the increment runs once per
     # retrace, which is exactly what the counter measures
     _TRACES[0] += 1  # palmlint: ignore[trace-safety] — deliberate retrace counter
@@ -258,7 +275,7 @@ def _fused_screen(table, xn2, scale, rows, qc, s):
     n2 = jnp.take(xn2, rows)  # (B,) cached |x - mu|^2
     sc = None if scale is None else jnp.take(scale, rows)
     vals, pidx = _screen_core(sub, n2, qc, s, sc)
-    return vals, jnp.take(rows, jnp.maximum(pidx, 0)), pidx < 0
+    return _pack_slate(vals, jnp.take(rows, jnp.maximum(pidx, 0)), pidx)
 
 
 @functools.partial(jax.jit, static_argnames=("s",))
@@ -272,7 +289,7 @@ def _fused_screen_full(table, xn2, scale, mask, qc, s):
     _TRACES[0] += 1  # palmlint: ignore[trace-safety] — deliberate retrace counter
     n2 = jnp.where(mask, xn2, kops.BIG_NORM2)
     vals, pidx = _screen_core(table, n2, qc, s, scale)
-    return vals, pidx, pidx < 0
+    return _pack_slate(vals, pidx, pidx)
 
 
 class VerifyEngine:
@@ -296,6 +313,7 @@ class VerifyEngine:
             "hits": 0,  # passes served from an already-compiled trace
             "h2d_bytes": 0,  # host->device: arena uploads + rows + queries
             "d2h_bytes": 0,  # device->host: downloaded slates
+            "d2h_copies": 0,  # device->host transfers: one packed slate a pass
             "uploads": 0,  # arena builds/extends
             "fallbacks": 0,  # queries re-screened on host (cert failures)
             "candidates": 0,  # candidate rows the passes asked for
@@ -428,7 +446,8 @@ class VerifyEngine:
     def _launch(self, view: DeviceView, trows: np.ndarray, Qc: np.ndarray,
                 s: int):
         """Bucket-pad rows and queries, launch the fused pass, download the
-        slate. Returns host (vals (m, s) f32, rows (m, s) int64, -1 padded).
+        packed slate in one copy. Returns host (vals (m, s) f32, rows (m, s)
+        int64, -1 padded).
         Dispatch and trace/hit accounting are serialized under the engine
         lock (the before/after _TRACES hit attribution needs launches not
         to interleave); the expensive part — blocking on the device result
@@ -458,14 +477,14 @@ class VerifyEngine:
                     mask = np.zeros(view.cap, bool)
                     mask[trows] = True
                     self.stats["h2d_bytes"] += mask.nbytes + qpad.nbytes
-                    vals, srows, invalid = _fused_screen_full(
+                    packed = _fused_screen_full(
                         view.table, view.xn2, view.scale, jnp.asarray(mask),
                         jnp.asarray(qpad), s)
                 else:
                     rows = np.full(bb, view.n, np.int32)  # pad: the sentinel
                     rows[: trows.size] = trows
                     self.stats["h2d_bytes"] += rows.nbytes + qpad.nbytes
-                    vals, srows, invalid = _fused_screen(
+                    packed = _fused_screen(
                         view.table, view.xn2, view.scale, jnp.asarray(rows),
                         jnp.asarray(qpad), s)
                 if _TRACES[0] == before:  # served from a compiled trace
@@ -473,17 +492,16 @@ class VerifyEngine:
                 self.stats["traces"] = _TRACES[0]
         # jax dispatch is asynchronous: np.asarray blocks on the result, so
         # it must not run under the lock
-        with span("repro.verify.wait"):
-            vals = np.asarray(vals)[:m]
-            srows = np.asarray(srows)[:m].astype(np.int64)
-            invalid = np.asarray(invalid)[:m]
+        with span("repro.verify.wait", copies=1):
+            slate = np.asarray(packed)
         with self._lock:
-            self.stats["d2h_bytes"] += (vals.nbytes + srows.nbytes
-                                        + invalid.nbytes)
+            self.stats["d2h_copies"] += 1
+            self.stats["d2h_bytes"] += slate.nbytes
+        vals, srows = _unpack_slate(slate[:m], s)
         # sentinel/masked-out rows surface only when the slate outsizes
         # the candidates; their BIG screen value or row index flags them
-        srows = np.where(invalid | (srows >= view.n) | (vals >= 1e29), -1,
-                         srows)
+        # (view.n stays a host test: traced, every arena extend would retrace)
+        srows = np.where((srows >= view.n) | (vals >= 1e29), -1, srows)
         return vals, srows
 
     def screen_topk(

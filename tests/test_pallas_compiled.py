@@ -95,6 +95,7 @@ def test_fused_gather_pass_compiles(tpu_branch, one_chip, dtype, rows):
         table, xn2, scale, _sds((rows,), jnp.int32, one_chip),
         _sds((_CHUNK_M, D), jnp.float32, one_chip))
     assert "tpu_custom_call" in hlo  # the Pallas kernel, not the XLA twin
+    assert f"s32[{_CHUNK_M},{2 * S}]" in hlo  # the packed slate comes out
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
@@ -107,6 +108,7 @@ def test_fused_full_coverage_pass_compiles(tpu_branch, one_chip, dtype):
         table, xn2, scale, _sds((CAP,), jnp.bool_, one_chip),
         _sds((_CHUNK_M, D), jnp.float32, one_chip))
     assert "tpu_custom_call" in hlo
+    assert f"s32[{_CHUNK_M},{2 * S}]" in hlo
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))

@@ -4,6 +4,10 @@ tier x scalar/batch on well-conditioned data, error-bound-certified
 exactness on adversarially conditioned data, the steady-state zero-retrace
 guarantee of the shape-bucketed compile cache, and the arena lifecycle
 (one upload per table, in-place extends for append-only stores)."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -20,7 +24,14 @@ from repro.core import (
     SummarizationConfig,
     ed2,
 )
-from repro.core.verify_engine import get_engine
+from repro.core.verify_engine import (
+    VerifyEngine,
+    _bucket_batch,
+    _bucket_rows,
+    _screen_core,
+    get_engine,
+)
+from repro.kernels import ops as kops
 
 CFG = SummarizationConfig(series_len=64, n_segments=8, card_bits=6)
 
@@ -209,6 +220,80 @@ def test_prewarm_compiles_the_ladder_once():
     again = eng.prewarm(96, m=16, k=5, caps=[3000])
     assert again == 0  # everything already compiled
     assert compiled >= 0  # first call may share traces with earlier tests
+
+
+# ---------------------------------------------------------------------------
+# the packed slate: one download a pass, the same bits as three
+# ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("s", "full"))
+def _three_output_pass(table, xn2, scale, idx, qc, s, full):
+    """The verification pass as it was before the slate was packed:
+    values, rows and the invalid flags as three separate outputs."""
+    if full:
+        vals, pidx = _screen_core(
+            table, jnp.where(idx, xn2, kops.BIG_NORM2), qc, s, scale)
+        return vals, pidx, pidx < 0
+    sc = None if scale is None else jnp.take(scale, idx)
+    vals, pidx = _screen_core(jnp.take(table, idx, axis=0),
+                              jnp.take(xn2, idx), qc, s, sc)
+    return vals, jnp.take(idx, jnp.maximum(pidx, 0)), pidx < 0
+
+
+def _three_download_launch(view, trows, Qc, s):
+    """The slate as ``_launch`` returned it with three downloads."""
+    m = Qc.shape[0]
+    qpad = np.zeros((_bucket_batch(m), Qc.shape[1]), np.float32)
+    qpad[:m] = Qc
+    bb = max(_bucket_rows(trows.size), _bucket_rows(s, 8))
+    full = bb >= view.cap
+    if full:
+        idx = np.zeros(view.cap, bool)
+        idx[trows] = True
+    else:
+        idx = np.full(bb, view.n, np.int32)
+        idx[: trows.size] = trows
+    vals, srows, invalid = _three_output_pass(
+        view.table, view.xn2, view.scale, jnp.asarray(idx), jnp.asarray(qpad),
+        s=s, full=full)
+    vals = np.asarray(vals)[:m]
+    srows = np.asarray(srows)[:m].astype(np.int64)
+    invalid = np.asarray(invalid)[:m]
+    srows = np.where(invalid | (srows >= view.n) | (vals >= 1e29), -1, srows)
+    return vals, srows
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("variant,n,u", [
+    ("gather", 3000, 1500),
+    ("full", 3000, 2900),
+    ("gather_outsized", 3000, 12),  # s > u: sentinel pad rows surface
+    ("full_outsized", 40, 12),  # s > u: masked-out rows surface
+])
+def test_packed_slate_is_bitwise_the_three_downloads(variant, n, u, dtype):
+    X, Q = _data(n, seed=31), _queries(70, seed=37)
+    eng = VerifyEngine(dtype=dtype)
+    view = eng.build_view(X)
+    trows = np.sort(np.random.default_rng(41).choice(n, u, replace=False))
+    Qc = Q[:16] - view.mu[None, :]
+    s = 18
+    gathered0 = eng.stats["gathered_rows"]
+    vals, srows = eng._launch(view, trows, Qc, s)
+    # the variant the pass took: full coverage screens the whole capacity
+    assert (eng.stats["gathered_rows"] - gathered0 == view.cap) == (
+        variant.startswith("full"))
+    want_vals, want_rows = _three_download_launch(view, trows, Qc, s)
+    assert vals.dtype == want_vals.dtype and srows.dtype == want_rows.dtype
+    np.testing.assert_array_equal(vals.view(np.int32),
+                                  want_vals.view(np.int32))
+    np.testing.assert_array_equal(srows, want_rows)
+    assert (srows == -1).any() == variant.endswith("outsized")
+    assert eng.stats["d2h_copies"] == eng.stats["calls"] == 1
+    assert eng.stats["d2h_bytes"] == _bucket_batch(16) * 2 * s * 4
+    # one screen_topk of 70 queries verifies in two chunks: one copy each
+    calls0, copies0 = eng.stats["calls"], eng.stats["d2h_copies"]
+    eng.screen_topk(view, trows, Q, k=10)
+    assert eng.stats["calls"] - calls0 == 2
+    assert eng.stats["d2h_copies"] - copies0 == eng.stats["calls"] - calls0
 
 
 # ---------------------------------------------------------------------------
