@@ -1,10 +1,11 @@
-"""The benchmark's own data: random-walk series and queries from a seed.
+"""Random walks: the collection and its queries from a seed.
 
 A random walk is the cumulative sum of N(0, 1) steps, drawn in float32 on
 the device in one jitted call per batch and copied to the host, where the
 system ingests it. Batch ``b`` of a collection depends only on the seed and
 ``b``, so a stream can be generated batch by batch and the same seed always
-gives the same series.
+gives the same series. Queries are walks of their own, from a stream no
+data batch uses.
 """
 from __future__ import annotations
 
@@ -53,6 +54,7 @@ def batches(seed: int, n_batches: int, batch: int, d: int,
     return out
 
 
-def queries(seed: int, n: int, d: int) -> np.ndarray:
-    """(n, d) query walks from a stream no data batch uses."""
+def queries(seed: int, n: int, d: int, history: list) -> np.ndarray:
+    """(n, d) query walks from a stream no data batch uses; the stored
+    ``history`` plays no part."""
     return random_walks(seed, QUERY_STREAM, n, d)

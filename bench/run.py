@@ -4,13 +4,15 @@
 
 A cell is ``<config>.<traffic>`` as ``BENCHMARK.json`` names it. The run
 builds the configuration's deployment from ``bench/configs/<config>.json``
-and the seed, warms the cell's own shapes, then offers the traffic of
+(see :func:`deployment`) and its data and queries from the seed, by the
+generators that the configuration names in ``bench/generators/``; it warms
+the cell's own shapes, then offers the traffic of
 ``bench/mixes/<traffic>.json`` open loop through the system's gateway for
 ``--seconds``. Once the window has closed it checks the answers against the
 plain f64 reference and prints, as the last line of standard output, one
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
-(and ``breakdown`` under ``--trace 1``), then the numbers compared beside
-their limits. With ``--trace 0`` the metrics are the cell's end-to-end
+(and ``breakdown`` and ``idle_by_span`` under ``--trace 1``), then the
+numbers compared beside their limits. With ``--trace 0`` the metrics are the cell's end-to-end
 metrics; with ``--trace 1`` the window is profiled and the metrics are its
 per-layer ones. Each metric is read by ``bench/metrics/<name>.py``.
 
@@ -28,11 +30,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
 import math
 import os
+import re
 import resource
 import sys
 import time
@@ -87,14 +91,23 @@ def load_cell(workload: str, spec: Optional[dict] = None) -> dict:
     }
 
 
-def reader(name: str):
-    """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    mod_name = "bench_metric_" + name.replace(".", "_").replace("-", "_")
+@functools.cache
+def module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded by path: a metric's reader under
+    ``metrics``, a data or query generator under ``generators``."""
+    path = BENCH / kind / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: no {name!r} in bench/{kind}")
+    mod_name = f"bench_{kind}_" + re.sub(r"\W", "_", name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str):
+    """The ``read`` function of ``bench/metrics/<name>.py``."""
+    return module("metrics", name).read
 
 
 def require_accelerator(chips: int) -> list:
@@ -151,12 +164,54 @@ class Request:
     failed: bool = False
 
 
-ENGINE_KEYS = ("calls", "screened", "fallbacks", "traces")
+def counters(stats: dict) -> dict:
+    """The numeric counters of ``VerifyEngine.stats``: every key but its
+    batch histogram and its dtype."""
+    return {k: v for k, v in stats.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+# fields that the harness sets itself: the series length from the top
+# level, the arena dtype from ``arena_dtype``, ``k`` from the mix
+SET_BY_HARNESS = {"series_len", "summarization", "screen_dtype", "k"}
+
+
+def deployment(config: dict, k: int) -> tuple:
+    """The ``StreamConfig`` (with its ``SummarizationConfig``) and the
+    ``GatewayConfig`` that a configuration states. Each key of its ``index``
+    object sets the field of that name of ``SummarizationConfig`` or
+    ``StreamConfig``, each key of its ``gateway`` object that of
+    ``GatewayConfig``; ``series_len`` is the series length, ``arena_dtype``
+    the ``screen_dtype``, and ``k`` comes from the mix. A key that names no
+    such field stops the run, so that no file runs another deployment than
+    the one it states."""
+    from repro.core import GatewayConfig, StreamConfig, SummarizationConfig
+
+    def split(where: str, *classes) -> list[dict]:
+        parts = [{} for _ in classes]
+        for key, value in config[where].items():
+            hit = [i for i, cls in enumerate(classes)
+                   if key in {f.name for f in dataclasses.fields(cls)}]
+            if not hit or key in SET_BY_HARNESS:
+                raise SystemExit(
+                    f"bench: {where}.{key} in the configuration is no field "
+                    f"that it sets of "
+                    f"{' or '.join(c.__name__ for c in classes)}")
+            parts[hit[0]][key] = value
+        return parts
+
+    summ, stream = split("index", SummarizationConfig, StreamConfig)
+    (gate,) = split("gateway", GatewayConfig)
+    return (StreamConfig(summarization=SummarizationConfig(
+                series_len=int(config["series_len"]), **summ),
+                screen_dtype=config["arena_dtype"], **stream),
+            GatewayConfig(k=k, **gate))
 
 
 class Cell:
     """One deployment of a configuration and its traffic, built from the
-    seed through the system's own entry points."""
+    seed through the system's own entry points. The configuration's
+    fields and generators are resolved here, before any data is made."""
 
     def __init__(self, config: dict, mix: dict, seed: int):
         self.config, self.mix, self.seed = config, mix, seed
@@ -164,31 +219,25 @@ class Cell:
         self.k = int(mix["k"])
         # an exact request asks for recall 1: served on the exact tier only
         self.exact = float(mix["target_recall"]) >= 1.0
+        self.index_cfg, self.gateway_cfg = deployment(config, self.k)
+        self.data = module("generators", config["generator"])
+        self.make_queries = module("generators", config["queries"]).queries
 
     # ---- set-up
     def build(self, stream_s: float) -> None:
         """Generate the data, ingest it, upload the arena and prewarm the
         gateway; generate ``stream_s`` seconds of the ingest stream."""
-        from bench import data
-        from repro.core import (Gateway, GatewayConfig, StreamConfig,
-                                StreamingIndex, SummarizationConfig)
+        from repro.core import Gateway, StreamingIndex
         from repro.core.verify_engine import get_engine
 
-        c, ix = self.config, self.config["index"]
+        c = self.config
         t0 = time.perf_counter()
-        self.batches = data.batches(self.seed, c["batches"], c["batch"],
-                                    self.d)
-        log(f"[data] {c['batches']} batches of {c['batch']} random walks x "
-            f"{self.d} in {time.perf_counter() - t0:.1f}s")
+        self.batches = self.data.batches(self.seed, c["batches"], c["batch"],
+                                         self.d)
+        log(f"[data] {c['batches']} batches of {c['batch']} series x "
+            f"{self.d} ({c['generator']}) in {time.perf_counter() - t0:.1f}s")
         t0 = time.perf_counter()
-        self.idx = StreamingIndex(StreamConfig(
-            scheme=ix["scheme"], summarization=SummarizationConfig(
-                series_len=self.d, n_segments=ix["n_segments"],
-                card_bits=ix["card_bits"]),
-            buffer_entries=ix["buffer_entries"],
-            growth_factor=ix["growth_factor"], block_size=ix["block_size"],
-            ingest=ix["ingest"], storage=ix["storage"],
-            screen_dtype=c["arena_dtype"]))
+        self.idx = StreamingIndex(self.index_cfg)
         for t, x in enumerate(self.batches):
             self.idx.ingest(x, np.full(x.shape[0], t, np.int64))
         if not self.idx.drain(timeout=1800):
@@ -196,10 +245,7 @@ class Cell:
         log(f"[ingest] {self.idx.raw.n} series, {self.idx.n_partitions} "
             f"runs, ingest + drain {time.perf_counter() - t0:.1f}s")
         self.engine = get_engine()
-        g = c["gateway"]
-        self.gw = Gateway(self.idx, GatewayConfig(
-            deadline_ms=g["deadline_ms"], slo_p99_ms=g["slo_p99_ms"],
-            max_batch=g["max_batch"], k=self.k, autotune=g["autotune"]))
+        self.gw = Gateway(self.idx, self.gateway_cfg)
         # the prewarm runs on a zero table of the arena's capacity; before
         # the upload, so that the two never share the device
         t0 = time.perf_counter()
@@ -215,8 +261,8 @@ class Cell:
         if ing:
             period = ing["batch"] / ing["rate_series_per_s"]
             n_live = math.ceil(stream_s / period) + 1
-            self.live = data.batches(self.seed, n_live, ing["batch"], self.d,
-                                     first=len(self.batches))
+            self.live = self.data.batches(self.seed, n_live, ing["batch"],
+                                          self.d, first=len(self.batches))
 
     def window_of(self, acked_ts: int):
         """The (t0, t1) window of a request sent now, or None."""
@@ -260,8 +306,8 @@ class Cell:
         running through both where the mix has one."""
         import jax
 
-        from bench import data, traffic
         from bench import trace as tracing
+        from bench import traffic
 
         mix = self.mix
         warm = float(mix["warmup_s"])
@@ -269,7 +315,8 @@ class Cell:
         due_w = traffic.schedule(self.seed, 1, rate, warm, burst)
         due_m = traffic.schedule(self.seed, 2, rate, seconds, burst)
         n_burst = sum(self.burst_sizes())
-        Q = data.queries(self.seed, n_burst + due_w.size + due_m.size, self.d)
+        Q = self.make_queries(self.seed, n_burst + due_w.size + due_m.size,
+                              self.d, self.batches)
         ts0 = len(self.batches)
         row0 = self.warm_bursts(Q, ts0 - 1)
         T0 = time.perf_counter() + 0.05
@@ -299,7 +346,7 @@ class Cell:
         if trace:
             tracing.start(str(TRACE_DIR))
             t_on = time.perf_counter()
-        before = {k: self.engine.stats[k] for k in ENGINE_KEYS}
+        before = counters(self.engine.stats)
         compiles = []
 
         def on_compile(event, secs, **kw):
@@ -309,7 +356,7 @@ class Cell:
         jax.monitoring.register_event_duration_secs_listener(on_compile)
         time.sleep(max(0.0, w1 - time.perf_counter()))
         jax.monitoring.unregister_event_duration_listener(on_compile)
-        after = {k: self.engine.stats[k] for k in ENGINE_KEYS}
+        after = counters(self.engine.stats)
         in_use.append(memory(jax.local_devices(), "bytes_in_use"))
         log(f"[window] {len(compiles)} compilations inside the window; "
             f"device bytes in use at its open and close {in_use}")
@@ -318,13 +365,15 @@ class Cell:
             t_off = time.perf_counter()
             red = tracing.reduce(tracing.stop_and_load(str(TRACE_DIR)),
                                  t_off - t_on, PASSES, KERNELS)
+            log(f"[trace] {len(red['spans'])} program spans; reduced in "
+                f"{time.perf_counter() - t_off:.1f}s")
         client.stop()
         if feeder:
             feeder.stop()
             if feeder.error is not None:
                 raise RuntimeError("ingest failed") from feeder.error
-        win = Window(seconds=seconds, setup_s=setup_s, trace=red,
-                     engine={k: after[k] - before[k] for k in ENGINE_KEYS})
+        win = Window(seconds=seconds, setup_s=setup_s, trace=red, engine={
+            k: v - before.get(k, 0) for k, v in after.items()})
         self.Q = Q[row0:]
         self._collect(win, client, w0, w1, due_w.size)
         if feeder:
@@ -478,6 +527,7 @@ def run_cell(loaded: dict, seed: int, seconds: float, trace: bool,
         device["window_s"] = win.trace["window_s"]
         out["breakdown"] = {"device_ops": win.trace["device_ops"],
                             "idle_gaps": win.trace["idle_gaps"]}
+        out["idle_by_span"] = win.trace["idle_by_span"]
     out["checks"] = {k: {"value": v, "limit": lim}
                      for k, (v, lim) in checks.items()}
     return out

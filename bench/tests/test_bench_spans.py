@@ -91,8 +91,6 @@ def data():
 @pytest.fixture(scope="module")
 def window(data):
     red = reduce(data, 0.010)
-    red["spans"] = spans.program_spans(data)
-    red["idle_by_span"] = spans.idle_by_span(data, red["spans"])
     return run.Window(
         seconds=0.010, trace=red,
         engine={"calls": 2, "candidates": 6144, "gathered_rows": 7168},
@@ -153,7 +151,8 @@ def test_span_readers_read_nothing_without_spans(data, name):
     reqs = [run.Request(row=0, due=0.0, window=None, latency_ms=1.0,
                         resp=object(), failed=False)]
     bare = dict(reduce(data, 0.010), spans=[])
-    for win in (run.Window(seconds=0.010, trace=reduce(data, 0.010),
+    no_key = {k: v for k, v in bare.items() if k != "spans"}
+    for win in (run.Window(seconds=0.010, trace=no_key,
                            engine={"calls": 2}, requests=reqs),
                 run.Window(seconds=0.010, trace=bare, engine={"calls": 2},
                            requests=reqs),

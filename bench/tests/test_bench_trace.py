@@ -5,8 +5,9 @@ import pytest
 from bench import trace
 
 # one TPU plane: ops at [0, 2) and [1, 4) overlap, then [6, 7) ms; the
-# modules line holds one fused screen launch; the host was in a re-rank
-# during the 2 ms gap between 4 and 6 ms
+# modules line holds one fused screen launch; during the 2 ms gap between
+# 4 and 6 ms the host was in the benchmark's re-rank span, and for 1.5 ms
+# of it in a runtime download under that span
 XSPACE = """
 planes {
   id: 1
@@ -34,9 +35,11 @@ planes {
     id: 1 name: "python3" timestamp_ns: 0
     events { metadata_id: 1 offset_ps: 3500000000 duration_ps: 3000000000 }
     events { metadata_id: 2 offset_ps: 4500000000 duration_ps: 500000000 }
+    events { metadata_id: 3 offset_ps: 4500000000 duration_ps: 1500000000 }
   }
   event_metadata { key: 1 value { id: 1 name: "bench.rerank" } }
   event_metadata { key: 2 value { id: 2 name: "bench.submit" } }
+  event_metadata { key: 3 value { id: 3 name: "np.asarray(jax.Array)" } }
 }
 """
 
@@ -52,6 +55,7 @@ def reduced():
 def test_busy_is_the_union_of_op_intervals(reduced):
     assert reduced["n_devices"] == 1
     assert reduced["busy_s"] == pytest.approx(0.005)  # [0, 4) and [6, 7)
+    assert reduced["busy_s_per_device"] == pytest.approx([0.005])
     assert reduced["window_s"] == 0.010
 
 
@@ -87,7 +91,8 @@ def test_device_ops_sum_per_name(reduced):
 
 
 def test_idle_gap_named_by_the_host_event_overlapping_most(reduced):
+    """The runtime's event names the gap, not the spans around it."""
     assert len(reduced["idle_gaps"]) == 1
     name, seconds = reduced["idle_gaps"][0]
     assert seconds == pytest.approx(0.002)
-    assert name == "bench.rerank (100% of gap)"
+    assert name == "np.asarray(jax.Array) (75% of gap)"

@@ -49,9 +49,10 @@ def test_a_stall_makes_later_requests_late_from_their_due_time():
     win = run.Window(seconds=0.03)
     cell._collect(win, client, t0, t0 + 0.03, 0)
     lat = [r.latency_ms for r in win.requests]
-    # request 0 answered 5 ms after it was sent on time; 1 and 2 waited
-    # behind the stalled submit, and their wait counts
-    assert lat[0] == pytest.approx(5.0, abs=2.0)
+    # request 0 answered 5 ms after it was sent, timed from its due time;
+    # 1 and 2 waited behind the stalled submit, and their wait counts
+    s0 = client.sent[0]
+    assert lat[0] == pytest.approx((s0.sent - s0.due) * 1e3 + 5.0)
     assert lat[1] > 70.0 - 10.0 + 5.0 and lat[2] > 60.0 - 10.0
     assert win.lateness_s > 0.05
 

@@ -6,8 +6,9 @@ and that has an ``XLA Ops`` line. That line holds one event per
 operation that ran, named by its HLO text (which carries the operand
 shapes); busy time is the union of those intervals, averaged over the
 device planes. Launches of a jitted pass are the ``XLA Modules`` events
-whose name holds its jit name. Idle gaps are named by the host event that
-overlaps them most.
+whose name holds its jit name. Idle gaps are named by the runtime's host
+event that overlaps them most. The program's own spans, and the device's
+idle time put down to them, come from ``bench/spans.py``.
 """
 from __future__ import annotations
 
@@ -77,13 +78,17 @@ def reduce(data, window_s: float, modules: tuple = (), ops: tuple = ()) -> dict:
     """Device numbers of a traced window of ``window_s`` seconds.
 
     Returns ``busy_s`` (union of operation intervals, mean over the device
-    planes that ran operations), ``window_s``, ``n_devices``, ``device_ops``
+    planes that ran operations), ``busy_s_per_device`` (each plane's, in
+    the trace's order), ``window_s``, ``n_devices``, ``device_ops``
     (the ten operations, by :func:`short_op`, with most time summed over
     devices), ``idle_gaps`` (the ten longest gaps between operations on the
     first device, named by the host), ``launches``: for each name in
     ``modules``, the seconds of each module event whose name contains it,
-    and ``kernel_ops``: for each name in ``ops``, (full HLO text, seconds)
-    of each operation event whose instruction name contains it."""
+    ``kernel_ops``: for each name in ``ops``, (full HLO text, seconds)
+    of each operation event whose instruction name contains it, and the
+    program's ``spans`` and ``idle_by_span`` (``bench/spans.py``)."""
+    from bench import spans
+
     devices, host = [], []
     for plane in data.planes:
         names = {line.name for line in plane.lines}
@@ -116,22 +121,33 @@ def reduce(data, window_s: float, modules: tuple = (), ops: tuple = ()) -> dict:
         gaps.append((s1 - e0, e0, s1))
     gaps.sort(reverse=True)
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
+    program = spans.program_spans(data)
     return {
         "busy_s": sum(busy) / len(busy) if busy else 0.0,
+        "busy_s_per_device": busy,
         "window_s": window_s,
         "n_devices": len(devices),
         "device_ops": [[n, s] for n, s in top_ops],
         "idle_gaps": [[_host_in(host, s, e), g / 1e9] for g, s, e in gaps[:10]],
         "launches": launches,
         "kernel_ops": kernel_ops,
+        "spans": program,
+        "idle_by_span": spans.idle_by_span(data, program),
     }
 
 
+OWN_SPANS = ("repro.", "bench.")  # the program's and the benchmark's spans
+
+
 def _host_in(host: list[tuple], s: float, e: float) -> str:
-    """The host event that overlaps [s, e] most, with the share of the gap
-    it covers, or 'no host event'."""
+    """The runtime's host event that overlaps [s, e] most, with the share
+    of the gap it covers, or 'no host event'. The program's and the
+    benchmark's own spans are left out: they enclose the gap, and
+    ``idle_by_span`` puts idle time down to them."""
     best, name = 0.0, None
     for n, hs, he in host:
+        if n.startswith(OWN_SPANS):
+            continue
         ov = min(he, e) - max(hs, s)
         if ov > best:
             best, name = ov, n
